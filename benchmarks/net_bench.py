@@ -87,6 +87,7 @@ try:
 except ImportError:  # run as a plain script: benchmarks/ is sys.path[0]
     from emit import write_net_bench
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import marathon_streams, merge_sort, server_sort
 from repro.core.partition import set_ranges
 from repro.data import SCENARIOS, TRACES, scenario_max_value, trace_max_value
@@ -895,6 +896,7 @@ def main() -> None:
         "BENCH_net.json is reproducible across invocations",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     n, repeats = (100_000, 2) if args.quick else (args.n, args.repeats)
     segs, length = args.segments, args.length
 

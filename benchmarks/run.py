@@ -24,6 +24,7 @@ try:
 except ImportError:  # pragma: no cover - python -m benchmarks.run
     from benchmarks import _bootstrap  # noqa: F401
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     RunStats,
     marathon_streams,
@@ -154,6 +155,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="400k values, sweep subset")
     args = ap.parse_args()
+    enable_compile_cache()
     n, repeats = (400_000, 2) if args.quick else (args.n, args.repeats)
     if args.quick:
         global SEGMENTS, LENGTHS

@@ -35,7 +35,7 @@ from repro.launch.mesh import make_production_mesh
 
 import functools
 
-from repro.distributed.compat import shard_map as compat_shard_map
+from jax import shard_map
 
 
 def main() -> None:
@@ -59,7 +59,7 @@ def main() -> None:
         capacity=capacity,
         presort_block=args.presort_block,
     )
-    shmapped = compat_shard_map(
+    shmapped = shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=(P(axis), P(axis), P(axis)),

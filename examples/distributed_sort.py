@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.distributed import gather_sorted, make_splitters, sort_sharded
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 from repro.core.runs import RunStats
 from repro.data import network_trace
 

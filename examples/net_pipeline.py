@@ -83,6 +83,7 @@ import numpy as np
 
 import _bootstrap  # noqa: F401
 
+from repro.compile_cache import enable_compile_cache
 from repro.data import SCENARIOS, TRACES, scenario_max_value, trace_max_value
 from repro.net import (
     MERGE_BACKENDS,
@@ -251,6 +252,7 @@ def main() -> None:
                     "table corruption — output stays byte-identical "
                     "(single-job mode only)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.merge_backend == "arena":
         print(

@@ -18,7 +18,8 @@ python benchmarks/run.py --quick
 echo "== device epoch kernels under interpret=True (repro.net.device_epoch) =="
 # The whole-epoch device engine's Pallas block-sort kernel, run in
 # interpret mode (no TPU in CI), asserted byte-identical to the fused
-# engine on a payload-attached leaf-spine epoch (ISSUE 8).
+# engine on a leaf-spine epoch.  A CPU check only: what the
+# chip runs is checked by `python chip_smoke.py` on a TPU host.
 PYTHONPATH=src python -c \
     "from repro.net import device_self_check; device_self_check(interpret=True)"
 

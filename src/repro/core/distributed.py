@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..distributed.compat import shard_map
+from jax import shard_map
 
 
 def blockwise_sort_jax(x: jax.Array, block: int) -> jax.Array:
@@ -152,17 +152,19 @@ def pool_concat_sharded(
     """Distributed concatenation of per-server sorted range shards.
 
     Server ``s``'s shard is padded to the pool-wide capacity with the
-    dtype-max sentinel and placed on device ``s`` of a one-axis mesh; one
-    tiled ``all_gather`` inside ``shard_map`` moves every shard to every
-    device — the paper's "concatenate" executed as the collective the pod
-    fabric would use — and the host compacts by the true shard lengths
+    dtype-max sentinel and the ``S`` shards are split in order over the
+    ``d`` devices of a one-axis mesh (``d`` divides ``S``); one tiled
+    ``all_gather`` inside ``shard_map`` moves every shard to every device —
+    the paper's "concatenate" executed as the collective the pod fabric
+    would use — and the host compacts by the true shard lengths
     (:func:`gather_sorted`), so sentinel collisions with real keys are
     harmless.
     """
-    num_servers = mesh.shape[axis_name]
-    if len(outs) != num_servers:
+    num_servers = len(outs)
+    if num_servers % mesh.shape[axis_name]:
         raise ValueError(
-            f"{len(outs)} shards for a {num_servers}-device {axis_name!r} axis"
+            f"{num_servers} shards do not split over a "
+            f"{mesh.shape[axis_name]}-device {axis_name!r} axis"
         )
     valid = np.array([o.size for o in outs], dtype=np.int64)
     cap = int(valid.max())
@@ -171,12 +173,15 @@ def pool_concat_sharded(
     padded = np.full((num_servers, cap), np.iinfo(np.int64).max, dtype=np.int64)
     for s, o in enumerate(outs):
         padded[s, : o.size] = o
+    from ..kernels.ops import x64_scope
+
     fn = _pool_gather(mesh, axis_name)
-    gathered = np.asarray(
-        jax.device_get(
-            fn(jax.device_put(padded, NamedSharding(mesh, P(axis_name, None))))
+    with x64_scope():  # int64 keys: no truncation at the device boundary
+        gathered = np.asarray(
+            jax.device_get(
+                fn(jax.device_put(padded, NamedSharding(mesh, P(axis_name, None))))
+            )
         )
-    )
     return gather_sorted(gathered, valid)
 
 
@@ -221,9 +226,9 @@ def pool_concat(
 
     ``disjoint=True`` (one control-plane epoch: server order is key-range
     order) concatenates — on the host, or with ``backend="shard_map"`` via
-    :func:`pool_concat_sharded` over ``mesh`` (built on demand from
-    :func:`repro.distributed.sharding.pool_mesh`; pure-numpy fallback when
-    the platform exposes fewer devices than servers).  ``disjoint=False``
+    :func:`pool_concat_sharded` over ``mesh`` (built on demand over the
+    devices present by :func:`repro.distributed.sharding.pool_mesh`).
+    ``disjoint=False``
     (epoched re-partitioning: server ranges overlap) k-way merges the
     sorted server streams — inherently sequential, always on the host.
     """
@@ -242,6 +247,5 @@ def pool_concat(
             from ..distributed.sharding import pool_mesh
 
             mesh = pool_mesh(len(outs), axis_name)
-        if mesh is not None:
-            return pool_concat_sharded(outs, mesh, axis_name)
+        return pool_concat_sharded(outs, mesh, axis_name)
     return np.concatenate(outs)

@@ -157,12 +157,12 @@ def merge_runs_flat(
             merge_runs([buf[s : s + l] for s, l in zip(starts, lengths)]),
             dtype=np.int64,
         )
-    from ..kernels import ops  # deferred: jax import is heavy
+    from ..kernels import bitonic, ops  # deferred: jax import is heavy
 
     if dtype.itemsize == 8:
         # 64-bit keys (packed key+payload-row records): the tournament must
         # run under an x64 scope, or jax would silently truncate to int32.
-        from jax.experimental import enable_x64 as _merge_scope
+        _merge_scope = ops.x64_scope
     else:
         import contextlib
 
@@ -172,26 +172,30 @@ def merge_runs_flat(
     buckets = (2 ** np.ceil(np.log2(lengths))).astype(np.int64)
     winners: list[np.ndarray] = []
     for B in np.unique(buckets):
-        sel = buckets == B
-        P = int(sel.sum())
-        if P == 1:
-            i = int(np.nonzero(sel)[0][0])
-            winners.append(buf[starts[i] : starts[i] + lengths[i]])
-            continue
-        with tr.span(
-            f"tournament:b{int(B)}", cat="server", tid=tid, runs=P
-        ):
-            rows = max(2, _next_pow2(P))
-            sl = lengths[sel]
-            mat = np.full((rows, int(B)), pad, dtype)
-            mat.flat[_ragged_gather(np.arange(P) * int(B), sl)] = buf[
-                _ragged_gather(starts[sel], sl)
-            ]
-            with _merge_scope():
-                merged = np.asarray(
-                    ops.merge_tournament(mat, interpret=interpret)
-                )
-            winners.append(merged[: int(sl.sum())])
+        B = int(B)
+        members = np.nonzero(buckets == B)[0]
+        # One tournament holds at most the kernel's VMEM cap of keys; a
+        # larger bucket splits, and its parts meet in the host merge below.
+        per_call = max(1, bitonic.TOURNAMENT_MAX_ELEMS // B)
+        for first in range(0, members.size, per_call):
+            part = members[first : first + per_call]
+            P = int(part.size)
+            if P == 1:
+                i = int(part[0])
+                winners.append(buf[starts[i] : starts[i] + lengths[i]])
+                continue
+            with tr.span(f"tournament:b{B}", cat="server", tid=tid, runs=P):
+                rows = max(2, _next_pow2(P))
+                sl = lengths[part]
+                mat = np.full((rows, B), pad, dtype)
+                mat.flat[_ragged_gather(np.arange(P) * B, sl)] = buf[
+                    _ragged_gather(starts[part], sl)
+                ]
+                with _merge_scope():
+                    merged = np.asarray(
+                        ops.merge_tournament(mat, interpret=interpret)
+                    )
+                winners.append(merged[: int(sl.sum())])
     if len(winners) == 1:
         return winners[0].astype(np.int64)
     with tr.span("winners", cat="server", tid=tid, runs=len(winners)):

@@ -83,7 +83,14 @@ def quantile_ranges(
             f"more segments ({num_segments}) than domain values ({max_value + 1})"
         )
     need = num_segments - 1
-    qs = np.quantile(np.asarray(sample), np.linspace(0, 1, num_segments + 1)[1:-1])
+    sample = np.asarray(sample)
+    # An empty sample (an empty stream) has no quantiles: every splitter
+    # then comes from the even padding below.
+    qs = (
+        np.quantile(sample, np.linspace(0, 1, num_segments + 1)[1:-1])
+        if sample.size
+        else np.zeros(0)
+    )
     splits = np.unique(np.floor(qs).astype(np.int64))
     # Strictly increasing interior splitters within (0, max_value+1).
     splits = splits[(splits > 0) & (splits <= max_value)][:need]

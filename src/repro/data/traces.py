@@ -64,7 +64,7 @@ def memory_trace(n: int = DEFAULT_N, seed: int = 2) -> np.ndarray:
     # Sequential-IO bursts: repeat the previous size with p=0.3 (gives the
     # pre-existing runs the paper's memory trace exhibits).
     rep = rng.random(n) < 0.3
-    rep[0] = False
+    rep[:1] = False  # n may be 0
     idx = np.arange(n)
     idx[rep] = 0
     np.maximum.accumulate(idx, out=idx)

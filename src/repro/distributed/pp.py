@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
+from jax import shard_map
 
 
 def gpipe(

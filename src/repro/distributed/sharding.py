@@ -17,8 +17,14 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .compat import make_mesh, shard_map
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the partitioner places
+    what the specs leave open, as every layer here expects."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,15 +121,14 @@ def fsdp_gather(ctx: ShardCtx, tree, spec_tree):
 
 def pool_mesh(num_servers: int, axis_name: str = "server"):
     """One-axis mesh for the egress server pool's distributed merge
-    (:func:`repro.core.distributed.pool_concat_sharded`): device ``s`` plays
-    compute server ``s``.  Returns ``None`` when the pool is trivial or the
-    platform exposes fewer devices than servers — on CPU, export
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=S`` (scripts/ci.sh
-    does) so the shard_map path runs; callers fall back to numpy otherwise.
+    (:func:`repro.core.distributed.pool_concat_sharded`) over the devices
+    that are present: the largest device count that divides
+    ``num_servers``, each device holding ``num_servers / d`` consecutive
+    server shards.  One device (a single chip, or the plain CPU backend)
+    gives a one-device mesh, on which the collective still runs.
     """
-    if num_servers < 2 or len(jax.devices()) < num_servers:
-        return None
-    return make_mesh((num_servers,), (axis_name,))
+    d = max(c for c in range(1, len(jax.devices()) + 1) if num_servers % c == 0)
+    return make_mesh((d,), (axis_name,))
 
 
 def local_ctx() -> ShardCtx:

@@ -1,11 +1,26 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels, and the rule that picks
+each call's lowering.
 
-``interpret`` defaults to True off-TPU (this container validates kernels in
-interpret mode on CPU); on a TPU backend the same calls compile to Mosaic.
+Every sort or merge entry point runs one of three lowerings:
+
+* ``"pallas"`` — the Mosaic kernel, on a TPU backend;
+* ``"interpret"`` — the same kernel in Pallas interpret mode, the default
+  off-TPU (CPU runs validate the kernels this way; it proves nothing about
+  what the chip's compiler accepts — ``chip_smoke.py`` and
+  ``tests/test_tpu_compile.py`` do);
+* ``"xla"`` — the plain-jnp network or ``jnp.sort``.  This is the dtype
+  rule: 64-bit keys (the dataplane's packed key+row records) never enter a
+  Pallas kernel, on any backend.  Off-TPU the run-arena tournament also
+  takes it, since interpreting its kernel is orders of magnitude slower.
+
+:data:`LOWERING_COUNTS` records each call's choice, so a run can show that
+no call on the chip fell back to interpret mode or to XLA.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
 
 import jax
@@ -15,6 +30,15 @@ import numpy as np
 from . import bitonic
 from .flash_attention import flash_attention as _flash_attention
 
+#: Calls of the sort/merge entry points by ``"<op>:<lowering>:<key
+#: dtype>"``.  A call made while a larger program is traced (the device
+#: epoch's block sort) counts once per trace, not once per execution.
+LOWERING_COUNTS: collections.Counter = collections.Counter()
+
+
+def reset_lowering_counts() -> None:
+    LOWERING_COUNTS.clear()
+
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -22,6 +46,48 @@ def _on_tpu() -> bool:
 
 def _interpret_default(interpret: bool | None) -> bool:
     return (not _on_tpu()) if interpret is None else interpret
+
+
+def x64_scope():
+    """The one x64 scope of the repo: 64-bit keys (the dataplane's packed
+    key+row records) are traced and dispatched inside it, or jax would
+    truncate them to 32 bits at the jit boundary."""
+    return jax.enable_x64(True)
+
+
+def _kernel_scope(lowering: str):
+    """Kernels trace with x64 off, even inside an x64 program (the device
+    epoch): their keys are 32-bit, and Mosaic rejects the int64 scalars a
+    bare int becomes under x64."""
+    if lowering == "xla":
+        return contextlib.nullcontext()
+    return jax.enable_x64(False)
+
+
+def _lowering(dtype, interpret: bool | None, op: str) -> str:
+    """Pick and count the lowering of one call (module docstring)."""
+    dtype = np.dtype(dtype)
+    if dtype.itemsize == 8:
+        lowering = "xla"
+    else:
+        lowering = "interpret" if _interpret_default(interpret) else "pallas"
+    if op == "merge_tournament" and lowering == "interpret":
+        lowering = "xla"
+    LOWERING_COUNTS[f"{op}:{lowering}:{dtype.name}"] += 1
+    return lowering
+
+
+def _kernel_only(fn, x, interpret: bool | None, op: str, *args):
+    """Call ``fn(*args, interpret=...)`` for an entry point that has only
+    the kernel form."""
+    if np.dtype(x.dtype).itemsize == 8:
+        raise TypeError(
+            f"{op}: 64-bit keys never enter a Pallas kernel; use "
+            "sort_rows_padded / merge_tournament, which lower them via XLA"
+        )
+    lowering = _lowering(x.dtype, interpret, op)
+    with _kernel_scope(lowering):
+        return fn(*args, interpret=lowering == "interpret")
 
 
 def _next_pow2(n: int) -> int:
@@ -47,12 +113,11 @@ def _check_sort_keys(x, op: str) -> None:
     if dtype.itemsize == 8 and not jax.config.jax_enable_x64:
         raise TypeError(
             f"{op}: 64-bit keys require an x64 scope "
-            "(jax.experimental.enable_x64()); without it the jit boundary "
+            "(ops.x64_scope()); without it the jit boundary "
             "would silently truncate them to 32 bits"
         )
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def blockwise_sort(
     x: jax.Array, block: int, interpret: bool | None = None
 ) -> jax.Array:
@@ -65,12 +130,16 @@ def blockwise_sort(
     (n,) = x.shape
     if block & (block - 1) or n % block:
         raise ValueError(f"n={n} block={block}: need pow2 block dividing n")
+    return _kernel_only(_blockwise_sort, x, interpret, "blockwise_sort", x, block)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _blockwise_sort(x: jax.Array, block: int, interpret: bool) -> jax.Array:
+    (n,) = x.shape
     rows = n // block
-    rpt = _row_tile(rows)
     out = bitonic.sort_tiles(
-        x.reshape(rows, block),
-        rows_per_tile=rpt,
-        interpret=_interpret_default(interpret),
+        x.reshape(rows, block), rows_per_tile=_row_tile(rows),
+        interpret=interpret,
     )
     return out.reshape(n)
 
@@ -85,7 +154,7 @@ def _row_tile(rows: int, target: int = 8) -> int:
 
 def sort_rows_padded(x: jax.Array, interpret: bool | None = None) -> jax.Array:
     """Row sort for an arbitrary row count: the fused hop engine's one
-    device call per switch hop.
+    device call per switch hop, and the device epoch's block sort.
 
     Pads the row dimension up to a multiple of 8 with dtype-max rows so the
     grid always tiles 8 rows per kernel invocation (``sort_rows`` would fall
@@ -94,65 +163,78 @@ def sort_rows_padded(x: jax.Array, interpret: bool | None = None) -> jax.Array:
     contract); ragged *columns* are the caller's padding, done once per hop.
     Keys must be integers narrow enough for the active precision
     (:func:`_check_sort_keys`) — the guard runs pre-dispatch so a 64-bit
-    column without an x64 scope raises instead of truncating.
+    column without an x64 scope raises instead of truncating.  64-bit keys
+    sort with ``jnp.sort`` (the dtype rule); for integers the result is the
+    kernel's, element for element.
     """
     _check_sort_keys(x, "sort_rows_padded")
     b = x.shape[1]
     if b & (b - 1):
         raise ValueError(f"column count must be a power of two, got {b}")
-    return _sort_rows_padded(x, interpret=interpret)
+    lowering = _lowering(x.dtype, interpret, "sort_rows_padded")
+    with _kernel_scope(lowering):
+        return _sort_rows_padded(x, lowering=lowering)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _sort_rows_padded(x: jax.Array, interpret: bool | None = None) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("lowering",))
+def _sort_rows_padded(x: jax.Array, lowering: str) -> jax.Array:
     rows, b = x.shape
     if rows == 0:
         return x
+    if lowering == "xla":
+        return jnp.sort(x, axis=1)
     pad = (-rows) % 8
     if pad:
         fill = jnp.full((pad, b), jnp.iinfo(x.dtype).max, x.dtype)
         x = jnp.concatenate([x, fill], axis=0)
     out = bitonic.sort_tiles(
-        x,
-        rows_per_tile=8,
-        interpret=_interpret_default(interpret),
+        x, rows_per_tile=8, interpret=lowering == "interpret"
     )
     return out[:rows]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def sort_rows(x: jax.Array, interpret: bool | None = None) -> jax.Array:
     """Sort each row of (rows, B); B power of two."""
+    return _kernel_only(_sort_rows, x, interpret, "sort_rows", x)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sort_rows(x: jax.Array, interpret: bool) -> jax.Array:
     return bitonic.sort_tiles(
-        x,
-        rows_per_tile=_row_tile(x.shape[0]),
-        interpret=_interpret_default(interpret),
+        x, rows_per_tile=_row_tile(x.shape[0]), interpret=interpret
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def sort_rows_kv(
     keys: jax.Array, vals: jax.Array, interpret: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """Row-wise key-value sort (MoE dispatch: key=expert id, val=token idx)."""
-    return bitonic.sort_tiles_kv(
-        keys,
-        vals,
-        rows_per_tile=_row_tile(keys.shape[0]),
-        interpret=_interpret_default(interpret),
+    return _kernel_only(
+        _sort_rows_kv, keys, interpret, "sort_rows_kv", keys, vals
     )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+def _sort_rows_kv(
+    keys: jax.Array, vals: jax.Array, interpret: bool
+) -> tuple[jax.Array, jax.Array]:
+    return bitonic.sort_tiles_kv(
+        keys, vals, rows_per_tile=_row_tile(keys.shape[0]),
+        interpret=interpret,
+    )
+
+
 def merge_rows(
     a: jax.Array, b: jax.Array, interpret: bool | None = None
 ) -> jax.Array:
     """Row-wise merge of two sorted (rows, B) arrays -> (rows, 2B)."""
+    return _kernel_only(_merge_rows, a, interpret, "merge_rows", a, b)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _merge_rows(a: jax.Array, b: jax.Array, interpret: bool) -> jax.Array:
     return bitonic.merge_tiles(
-        a,
-        b,
-        rows_per_tile=_row_tile(a.shape[0]),
-        interpret=_interpret_default(interpret),
+        a, b, rows_per_tile=_row_tile(a.shape[0]), interpret=interpret
     )
 
 
@@ -168,25 +250,34 @@ def merge_tournament(x: jax.Array, interpret: bool | None = None) -> jax.Array:
     contract that keeps the jit cache to a handful of compiled shapes.
 
     On TPU the matrix stays VMEM-resident for all rounds in one Pallas call
-    (:func:`repro.kernels.bitonic.tournament_tiles`, up to its VMEM cap);
-    elsewhere the *identical* stage schedule lowers through XLA on the host
-    backend — Pallas interpret mode would re-trace the network per stage and
-    is orders of magnitude slower, which matters because this op backs a
-    benchmarked server hot path (unlike the validation-only kernel tests).
+    (:func:`repro.kernels.bitonic.tournament_tiles`; keys narrower than 32
+    bits are widened to int32 for it, which keeps their dtype-max pads
+    maximal); ``P * B`` above :data:`~repro.kernels.bitonic.
+    TOURNAMENT_MAX_ELEMS` raises there — the caller splits such a bucket.
+    64-bit keys, and every call off-TPU, lower the *identical* stage
+    schedule through XLA (:func:`repro.kernels.bitonic.
+    tournament_merge_array`).
     """
     _check_sort_keys(x, "merge_tournament")
     P, B = x.shape
     if P & (P - 1) or B & (B - 1):
         raise ValueError(f"tournament shape must be powers of two, got {x.shape}")
-    return _merge_tournament(x, interpret=interpret)
+    lowering = _lowering(x.dtype, interpret, "merge_tournament")
+    if lowering == "pallas" and P * B > bitonic.TOURNAMENT_MAX_ELEMS:
+        raise ValueError(
+            f"tournament {P}x{B} exceeds the kernel's "
+            f"{bitonic.TOURNAMENT_MAX_ELEMS}-key VMEM cap; split the bucket"
+        )
+    with _kernel_scope(lowering):
+        return _merge_tournament(x, lowering=lowering)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _merge_tournament(x: jax.Array, interpret: bool | None = None) -> jax.Array:
-    P, B = x.shape
-    if _interpret_default(interpret) or P * B > bitonic.TOURNAMENT_MAX_ELEMS:
+@functools.partial(jax.jit, static_argnames=("lowering",))
+def _merge_tournament(x: jax.Array, lowering: str) -> jax.Array:
+    if lowering == "xla":
         return bitonic.tournament_merge_array(x)
-    return bitonic.tournament_tiles(x, interpret=False)
+    wide = x.astype(jnp.int32) if x.dtype.itemsize < 4 else x
+    return bitonic.tournament_tiles(wide, interpret=False).astype(x.dtype)
 
 
 def flash_attention(

@@ -7,7 +7,7 @@ before first jax init; smoke tests must keep seeing 1 device).
 
 from __future__ import annotations
 
-from ..distributed.compat import make_mesh as _compat_make_mesh
+from ..distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,8 +15,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips across DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)

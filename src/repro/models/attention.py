@@ -19,7 +19,7 @@ from jax.sharding import PartitionSpec as P
 from ..configs.base import ModelConfig
 from ..distributed.sharding import ShardCtx
 from .layers import apply_rope, dense_init
-from ..distributed.compat import shard_map
+from jax import shard_map
 
 
 def init_attn(key, cfg: ModelConfig, dtype):

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import ShardCtx
-from ..distributed.compat import shard_map
+from jax import shard_map
 
 
 def dense_init(key, d_in: int, d_out: int, dtype, scale: float | None = None):

@@ -28,7 +28,7 @@ from ..configs.base import ModelConfig, MoEConfig
 from ..distributed.sharding import ShardCtx
 from .layers import activation, dense_init
 from .mlp import init_mlp, mlp, spec_mlp
-from ..distributed.compat import shard_map
+from jax import shard_map
 
 
 def padded_experts(num_experts: int, multiple: int = 16) -> int:

@@ -459,6 +459,7 @@ def run_graph_device(
     metrics=None,
     int_telemetry: bool = False,
     network=None,
+    ship_emission: bool = False,
 ):
     """Execute a fabric epoch as one compiled device program.
 
@@ -467,6 +468,9 @@ def run_graph_device(
     (scalar-)equal per-hop stats.  Exactly one host→device transfer (the
     donated ingress buffers) and one device→host transfer (the result
     pytree) happen per call, counted in :data:`TRANSFER_COUNTS`.
+    ``ship_emission=True`` fetches the per-hop taps of an observed run even
+    when nothing observes it, so every hop's stats carry their packets'
+    ship indices (the ``run_hop`` contract of :func:`device_hop`).
     """
     tr = tracer or NULL_TRACER
     if int_telemetry or batch.int_meta is not None:
@@ -484,12 +488,14 @@ def run_graph_device(
             graph, batch, spec, "fused",
             tracer=tracer, metrics=metrics, network=network,
         )
-    from jax.experimental import enable_x64
-
     from ..core.partition import set_ranges
+    from ..kernels.ops import x64_scope
 
     carry_rows = batch.row_index is not None
-    collect = network is not None or metrics is not None or tr.enabled
+    collect = (
+        ship_emission or network is not None or metrics is not None
+        or tr.enabled
+    )
     ingress = split_by_flow(batch, graph.num_groups)
     group_ns = tuple(len(g) for g in ingress)
     ranges = spec.ranges
@@ -515,7 +521,7 @@ def run_graph_device(
         graph, spec, ranges, group_ns, carry_rows, use_kernel,
         KERNEL_INTERPRET, collect,
     )
-    with enable_x64():
+    with x64_scope():
         dev_args = _to_device((
             tuple(np.ascontiguousarray(g.values) for g in ingress),
             tuple(np.ascontiguousarray(g.row_index) for g in ingress)
@@ -642,15 +648,21 @@ def device_hop(
         raise ValueError(
             "engine 'device' does not support INT telemetry — use 'fused'"
         )
-    out, stats = run_graph_device(graph, batch, spec, tracer=tracer)
+    out, stats = run_graph_device(
+        graph, batch, spec, tracer=tracer, ship_emission=True
+    )
     return out, stats[0]
 
 
 def device_self_check(interpret: bool = True, n: int = 4096,
                       seed: int = 0) -> None:
-    """CI probe: run a small epoch with the Pallas block-sort kernel forced
+    """CPU check: run a small epoch with the Pallas block-sort kernel forced
     (``interpret=True`` exercises the kernel path on CPU-only runners) and
     assert byte-identity against the fused per-hop engine.
+
+    Interpret mode proves nothing about the chip — not that Mosaic lowers
+    the kernel, nor what it computes there.  ``chip_smoke.py`` at the repo
+    root is the chip's check.
     """
     global KERNEL_INTERPRET
     from .topology import leaf_spine_graph, run_graph

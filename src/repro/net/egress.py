@@ -81,11 +81,11 @@ class ServerPool:
     or the device-resident ``"arena"`` tournament — byte-identical
     ``(output, passes)``, different wall-clock.  ``pool_backend`` selects
     the *distributed* merge that reassembles the shard outputs: ``"numpy"``
-    (default) or ``"shard_map"`` — per-server shards placed one-per-device
-    on a host ``("server",)`` mesh and concatenated with one collective
-    (:func:`repro.core.distributed.pool_concat_sharded`); when the platform
-    exposes fewer devices than servers it falls back to numpy (run CPU tests
-    under ``XLA_FLAGS=--xla_force_host_platform_device_count=S``).
+    (default) or ``"shard_map"`` — per-server shards split over a
+    ``("server",)`` mesh of the devices present and concatenated with one
+    collective (:func:`repro.core.distributed.pool_concat_sharded`; one
+    device per server on a host with ``S`` of them, one device for all on a
+    single chip).
 
     ``recovery`` turns on every member server's loss-recovery mode (seq
     dedup + reorder-overflow spill) — required when the delivered wire is

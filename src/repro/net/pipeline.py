@@ -210,7 +210,7 @@ def run_pipeline(
     tournament — byte-identical ``(output, passes)``, the
     ``server_throughput`` bench section measures the difference);
     ``pool_backend`` picks the pool's distributed merge (``"numpy"`` or
-    ``"shard_map"`` with numpy fallback).
+    the ``"shard_map"`` collective over the devices present).
 
     Observability (all opt-in and output-transparent — the sorted stream,
     passes, and epoch structure are byte-identical instrumented or not):

@@ -17,7 +17,7 @@ from repro.core.distributed import (
     sort_sharded,
 )
 from repro.core.runs import RunStats
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 
 
 def main() -> None:

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 from repro.distributed.sharding import ShardCtx
 from repro.models import moe as moe_mod
 

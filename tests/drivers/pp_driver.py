@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.compat import make_mesh
+from repro.distributed.sharding import make_mesh
 from repro.distributed.pp import gpipe, sequential_reference
 
 

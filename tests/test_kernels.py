@@ -1,7 +1,10 @@
-"""Per-kernel sweeps: Pallas (interpret=True) vs pure-jnp oracle.
+"""Per-kernel sweeps in Pallas interpret mode vs pure-jnp oracle.
 
 Every kernel is swept over shapes and dtypes and asserted allclose (exact
 for sorts — integer/float compare-exchange is exact; tolerant for attention).
+Interpret mode checks what a kernel computes, not that the chip's compiler
+accepts it: ``test_tpu_compile.py`` compiles the main path's kernels for a
+described v5e, and ``chip_smoke.py`` runs them on one.
 """
 
 import jax
@@ -154,13 +157,11 @@ def test_sort_guards_reject_int64_without_x64():
 def test_sort_rows_padded_int64_packed_payload_records():
     """64-bit packed (key << nbits) | row records, non-pow2 row count: the
     row padding stays distinct and the payload row indices ride the sort."""
-    from jax.experimental import enable_x64
-
     rng = np.random.default_rng(0)
     rows, b, nbits = 5, 16, 20  # 5 rows: exercises the pad-to-8 path
     keys = rng.integers(0, 1 << 40, size=(rows, b)).astype(np.int64)
     rec = (keys << nbits) | np.arange(rows * b, dtype=np.int64).reshape(rows, b)
-    with enable_x64():
+    with ops.x64_scope():
         out = np.asarray(ops.sort_rows_padded(jnp.asarray(rec)))
     np.testing.assert_array_equal(out, np.sort(rec, axis=1))
     # unpacked keys sorted; every payload row index survives the pack
@@ -171,8 +172,6 @@ def test_sort_rows_padded_int64_packed_payload_records():
 
 
 def test_merge_tournament_int64_packed_runs():
-    from jax.experimental import enable_x64
-
     rng = np.random.default_rng(1)
     P, B, nbits = 4, 32, 12
     pad = np.iinfo(np.int64).max
@@ -186,7 +185,7 @@ def test_merge_tournament_int64_packed_runs():
         mat[i, :ln] = packed
         want.append(packed)
         row += int(ln)
-    with enable_x64():
+    with ops.x64_scope():
         out = np.asarray(ops.merge_tournament(jnp.asarray(mat)))
     total = int(lens.sum())
     np.testing.assert_array_equal(out[:total], np.sort(np.concatenate(want)))

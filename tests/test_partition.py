@@ -96,6 +96,17 @@ def test_quantile_ranges_degenerate_sample_exact_count():
         assert ((seg >= 0) & (seg < S)).all()
 
 
+def test_quantile_ranges_empty_sample():
+    """An empty stream (oracle ranges over no keys) still gets exactly
+    num_segments strictly increasing ranges covering the domain."""
+    for maxv, S in [(20, 16), (10_000, 64)]:
+        r = quantile_ranges(np.zeros(0, dtype=np.int64), S, maxv)
+        assert r.shape == (S, 2)
+        assert (r[:, 1] > r[:, 0]).all()
+        assert r[0, 0] == 0 and r[-1, 1] == maxv + 1
+        np.testing.assert_array_equal(r[1:, 0], r[:-1, 1])
+
+
 def test_quantile_ranges_infeasible_raises():
     """More segments than domain values used to silently return fewer than
     num_segments ranges; now it raises like set_ranges does."""

@@ -22,6 +22,14 @@ from repro.data import TRACES, trace_max_value
 
 
 @pytest.mark.parametrize("trace_name", ["random", "network", "memory"])
+@pytest.mark.parametrize("n", [0, 1])
+def test_trace_generators_take_tiny_n(trace_name, n):
+    trace = TRACES[trace_name](n, seed=0)
+    assert trace.shape == (n,)
+    assert ((0 <= trace) & (trace <= trace_max_value(trace_name))).all()
+
+
+@pytest.mark.parametrize("trace_name", ["random", "network", "memory"])
 def test_full_pipeline_per_trace(trace_name):
     trace = TRACES[trace_name](100_000)
     maxv = trace_max_value(trace_name)
