@@ -139,13 +139,13 @@ def _network(xs, g, stages, width: int):
         else:
             p = _partner(xs, g, j, j // width, 0)
         lower = (g & j) == 0
-        asc = (g & k) == 0
-        take_min = ((g >> (j.bit_length() - 1)) ^ (g >> (k.bit_length() - 1))) & 1 == 0
         lo = jnp.where(lower, xs[0], p[0])
         hi = jnp.where(lower, p[0], xs[0])
         if len(xs) == 1:
+            take_min = ((g >> (j.bit_length() - 1)) ^ (g >> (k.bit_length() - 1))) & 1 == 0
             xs = (jnp.where(take_min, jnp.minimum(lo, hi), jnp.maximum(lo, hi)),)
         else:
+            asc = (g & k) == 0
             # where() over booleans has no Mosaic lowering; and/or does.
             swap = (asc & (lo > hi)) | (~asc & (lo < hi))
             xs = tuple(jnp.where(swap, q, x) for x, q in zip(xs, p))
