@@ -15,10 +15,11 @@ used for 64-bit keys and off-TPU.  The Pallas kernels never reshape the
 lane axis or reverse a value — Mosaic lowers neither — so in a kernel a
 stage finds each element's partner with two ``pltpu.roll`` rotations and
 an ``iota`` index mask (:func:`_network`), and pairs whole rows by aligned
-row slices when the stride spans rows.  Tiles are (rows, B) with B a power of two; the
-MXU is not involved (sorting is a VPU workload).  The kernels are traced
-with x64 off (``repro.kernels.ops`` sees to it): under an x64 scope every
-bare int in them would become an int64 scalar, which Mosaic rejects.
+row slices when the stride spans rows.  Tiles are (rows, B) with B a power
+of two; the MXU is not involved (sorting is a VPU workload).  The kernels
+are traced with x64 off (``repro.kernels.ops`` sees to it): under an x64
+scope every bare int in them would become an int64 scalar, which Mosaic
+rejects.
 """
 
 from __future__ import annotations
