@@ -474,7 +474,7 @@ def telemetry_overhead(n: int, repeats: int, seed: int = 0) -> dict:
     three — observability must be transparent — and the traced run's hop
     spans become the per-hop time/keys breakdown the report renders.
     """
-    from repro.obs import Tracer
+    from repro.obs import MetricsRegistry, Tracer
 
     cfg = dict(TELEMETRY_BENCH, n=n, repeats=repeats)
     trace = TRACES[cfg["trace"]](n, seed=seed)
@@ -501,9 +501,11 @@ def telemetry_overhead(n: int, repeats: int, seed: int = 0) -> dict:
     for _ in range(repeats):
         for mode in TELEMETRY_MODES:
             tracer = Tracer() if mode != "off" else None
+            metrics = MetricsRegistry() if mode != "off" else None
             t0 = time.perf_counter()
             res = run_pipeline(
-                trace, tracer=tracer, int_telemetry=mode == "int", **kw
+                trace, tracer=tracer, metrics=metrics,
+                int_telemetry=mode == "int", **kw
             )
             dt = time.perf_counter() - t0
             if mode == "traced" and dt <= min(times[mode], default=np.inf):

@@ -24,6 +24,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.obs.trace import NULL_TRACER
+from repro.transfers import fetch, to_device
 
 from .runs import run_starts
 
@@ -132,8 +133,10 @@ def merge_runs_flat(
     :func:`merge_two`): key ranges that do not fit the int32/uint16 pad
     sentinels, or totals too small to amortize a dispatch.
 
-    ``tracer`` records one ``tournament:b<B>`` span per length bucket and a
-    ``winners`` span for the final host merge (cat="server", lane ``tid``).
+    ``tracer`` records one ``tournament:b<B>`` span per tournament call,
+    whose ``bytes`` are its matrix's copy to the device and back (both made
+    through :mod:`repro.transfers`, and counted there), and a ``winners``
+    span for the final host merge (cat="server", lane ``tid``).
     """
     tr = tracer or NULL_TRACER
     starts = np.asarray(starts, dtype=np.int64)
@@ -184,16 +187,21 @@ def merge_runs_flat(
                 i = int(part[0])
                 winners.append(buf[starts[i] : starts[i] + lengths[i]])
                 continue
-            with tr.span(f"tournament:b{B}", cat="server", tid=tid, runs=P):
-                rows = max(2, _next_pow2(P))
+            rows = max(2, _next_pow2(P))
+            with tr.span(
+                f"tournament:b{B}", cat="server", tid=tid, runs=P,
+                bytes=2 * rows * B * dtype.itemsize,
+            ):
                 sl = lengths[part]
                 mat = np.full((rows, B), pad, dtype)
                 mat.flat[_ragged_gather(np.arange(P) * B, sl)] = buf[
                     _ragged_gather(starts[part], sl)
                 ]
                 with _merge_scope():
-                    merged = np.asarray(
-                        ops.merge_tournament(mat, interpret=interpret)
+                    merged = fetch(
+                        ops.merge_tournament(
+                            to_device(mat), interpret=interpret
+                        )
                     )
                 winners.append(merged[: int(sl.sum())])
     if len(winners) == 1:

@@ -38,11 +38,18 @@ columns, HopStats scalars, and server pass counts — is pinned by
 ``tests/test_device_epoch.py`` across the scenario × topology × range-mode
 matrix.
 
-Observability: with no tracer/metrics/network attached the program returns
-only the egress columns + per-hop stat scalars (one fetch).  When the run
-is observed, the *same single fetch* additionally carries every hop's
-output columns and per-key ship indices; the host then replays the
-bookkeeping — per-hop spans, metrics counters, and the
+Observability: a tracer never changes the program.  The host side of an
+epoch runs in the spans ``epoch:build`` (a program built on a cache
+miss), ``epoch:put``, ``epoch:device`` (dispatch until the result is
+ready) and ``epoch:fetch``; inside the program, ``jax.named_scope`` names
+each hop (``hop_<name>``), its stages (``route``, ``rank``,
+``block_sort``, ``emit``, ``ship``) and the uplink merge
+(``uplink_merge``), so a profiler trace attributes every device operation
+to a stage.  With no metrics registry or network attached the program
+returns only the egress columns + per-hop stat scalars (one fetch).  When
+``metrics``/``network`` observe the run, the *same single fetch*
+additionally carries every hop's output columns and per-key ship indices;
+the host then replays the bookkeeping — metrics counters and the
 :class:`~repro.net.timing.GraphTimer` emission cuts — over reconstructed
 :class:`~repro.net.wire.WireBatch` objects, so the timing overlay sees
 exactly what the per-hop loop would have shown it.
@@ -62,6 +69,13 @@ import warnings
 import numpy as np
 
 from repro.obs.trace import NULL_TRACER
+from repro.transfers import (  # noqa: F401  (TRANSFER_COUNTS: re-exported)
+    TRANSFER_COUNTS,
+    fetch,
+    nbytes,
+    reset_transfer_counts,
+    to_device,
+)
 
 from .engine import HopSpec, HopStats
 from .wire import (
@@ -71,36 +85,12 @@ from .wire import (
     split_by_flow,
 )
 
-#: Host↔device transfers performed by this module (one ``device_put`` of the
-#: ingress pytree in, one ``device_get`` of the result pytree out, per
-#: epoch).  The transfer-count acceptance check reads and resets these.
-TRANSFER_COUNTS = {"to_device": 0, "to_host": 0}
-
 #: Test/CI hook: force the Pallas block-sort kernel's interpret mode
 #: (None = the platform default chosen by :mod:`repro.kernels.ops`).
 KERNEL_INTERPRET: bool | None = None
 
 _PROGRAM_CACHE: dict = {}
 _PROGRAM_CACHE_MAX = 64
-
-
-def reset_transfer_counts() -> None:
-    TRANSFER_COUNTS["to_device"] = 0
-    TRANSFER_COUNTS["to_host"] = 0
-
-
-def _to_device(tree):
-    import jax
-
-    TRANSFER_COUNTS["to_device"] += 1
-    return jax.device_put(tree)
-
-
-def _fetch(tree):
-    import jax
-
-    TRANSFER_COUNTS["to_host"] += 1
-    return jax.device_get(tree)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -149,6 +139,15 @@ def _stable_perm(key, n: int):
     return packed & ((1 << ibits) - 1), packed >> ibits
 
 
+def _scatter_rows(tgt, mat, n: int):
+    """The sorted ``(R, L)`` matrix's cells scattered to their grouped
+    slots ``tgt`` (pad cells aim at slot ``n``, which is dropped)."""
+    import jax.numpy as jnp
+
+    out = jnp.zeros(n + 1, jnp.int64).at[tgt].set(mat.reshape(-1))
+    return out[:n]
+
+
 def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
                 vbits: int, use_kernel: bool, interpret: bool | None):
     """One hop, traced: returns the hop's wire columns + stat scalars.
@@ -162,150 +161,171 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
     ``vbits`` is the key domain's bit width (0 when packed sorts are
     infeasible — huge domains fall back to stable argsorts, byte-identical
     but slower).
+
+    Each stage runs in a ``jax.named_scope`` of its name (``route``,
+    ``rank``, ``block_sort``, ``emit``, ``ship``), which names its
+    operations in a profiler trace and changes nothing else.
     """
+    import jax
     import jax.numpy as jnp
 
     i64 = jnp.int64
     n = int(vals.shape[0])
     packable = vbits > 0
-    seg = jnp.searchsorted(bounds, vals, side="right").astype(i64)
-    if packable:
-        order, seg_g = _stable_perm(seg, n)
-    else:
-        order = jnp.argsort(seg, stable=True)
-        seg_g = seg[order]
-    counts = jnp.bincount(seg, length=S).astype(i64)
-    starts = jnp.concatenate([jnp.zeros(1, i64), jnp.cumsum(counts)[:-1]])
-    q = jnp.arange(n, dtype=i64) - starts[seg_g]  # in-segment position
-    ranks = jnp.zeros(n, i64).at[order].set(q)
-    grouped = vals[order]
+    with jax.named_scope("route"):
+        seg = jnp.searchsorted(bounds, vals, side="right").astype(i64)
+    with jax.named_scope("rank"):
+        if packable:
+            order, seg_g = _stable_perm(seg, n)
+        else:
+            order = jnp.argsort(seg, stable=True)
+            seg_g = seg[order]
+        counts = jnp.bincount(seg, length=S).astype(i64)
+        starts = jnp.concatenate(
+            [jnp.zeros(1, i64), jnp.cumsum(counts)[:-1]]
+        )
+        q = jnp.arange(n, dtype=i64) - starts[seg_g]  # in-segment position
+        ranks = jnp.zeros(n, i64).at[order].set(q)
+        grouped = vals[order]
 
     # -- block sort: rows of one padded (R, L) matrix -------------------
-    nblk = -(-counts // L)
-    blk_base = jnp.concatenate([jnp.zeros(1, i64), jnp.cumsum(nblk)[:-1]])
-    R = n // L + S  # static row budget; used rows are 0..sum(nblk)-1
-    row_of = blk_base[seg_g] + q // L
-    col_of = q % L
-    row_len = jnp.zeros(R, i64).at[row_of].add(1)
-    # Rows are (segment, block)-ordered and contiguous in grouped layout.
-    row_start = jnp.concatenate([jnp.zeros(1, i64), jnp.cumsum(row_len)[:-1]])
-    valid = jnp.arange(L, dtype=i64)[None, :] < row_len[:, None]
-    tgt = jnp.where(
-        valid, row_start[:, None] + jnp.arange(L, dtype=i64)[None, :], n
-    ).reshape(-1)
-    cbits = max(1, (L - 1).bit_length())
-    if rows is not None and packable and vbits + cbits <= 63:
-        # Record mode, packed: each cell carries ``(value << cbits) | col``
-        # so one key-only row sort both orders the values and tells every
-        # key which grouped slot it came from (``row_start[row] + col``) —
-        # the provenance gather that routes payload rows.  Pad cells keep
-        # the all-ones value with their own column in the low bits: they
-        # sort after every real key (ties with a real max-valued key break
-        # toward the real key's smaller column — the same stable tie-break
-        # as the fused engine's provenance lexsort) and land on dropped
-        # (``tgt == n``) output slots.
-        pad_val = (1 << vbits) - 1
-        cmask = (1 << cbits) - 1
-        cols = jnp.arange(L, dtype=i64)[None, :]
-        pk = jnp.broadcast_to((pad_val << cbits) | cols, (R, L))
-        pk = pk.at[row_of, col_of].set((grouped << cbits) | col_of)
-        spk = jnp.sort(pk, axis=1)
-        sorted_vals = spk >> cbits
-        src = jnp.clip(row_start[:, None] + (spk & cmask), 0, max(n - 1, 0))
-        stream = jnp.zeros(n + 1, i64).at[tgt].set(sorted_vals.reshape(-1))[:n]
-        src_slot = jnp.zeros(n + 1, i64).at[tgt].set(src.reshape(-1))[:n]
-        stream_rows = rows[order][src_slot]
-    elif rows is not None:
-        # Record mode, wide keys: a stable row argsort keeps the
-        # within-block arrival order on ties — the same tie-break as the
-        # fused engine's provenance lexsort — so payload rows follow their
-        # keys exactly.
-        pad = jnp.iinfo(i64).max
-        mat = jnp.full((R, L), pad, i64).at[row_of, col_of].set(grouped)
-        pmat = jnp.full((R, L), n, i64).at[row_of, col_of].set(
-            jnp.arange(n, dtype=i64)
+    with jax.named_scope("block_sort"):
+        nblk = -(-counts // L)
+        blk_base = jnp.concatenate(
+            [jnp.zeros(1, i64), jnp.cumsum(nblk)[:-1]]
         )
-        perm = jnp.argsort(mat, axis=1, stable=True)
-        sorted_vals = jnp.take_along_axis(mat, perm, axis=1)
-        sorted_pos = jnp.take_along_axis(pmat, perm, axis=1)
-        stream = jnp.zeros(n + 1, i64).at[tgt].set(sorted_vals.reshape(-1))[:n]
-        src_slot = jnp.zeros(n + 1, i64).at[tgt].set(sorted_pos.reshape(-1))[:n]
-        stream_rows = rows[order][src_slot]
-    elif use_kernel:
-        from ..kernels import ops  # deferred: only when the backend asks
+        R = n // L + S  # static row budget; used rows are 0..sum(nblk)-1
+        row_of = blk_base[seg_g] + q // L
+        col_of = q % L
+        row_len = jnp.zeros(R, i64).at[row_of].add(1)
+        # Rows are (segment, block)-ordered and contiguous in grouped layout.
+        row_start = jnp.concatenate(
+            [jnp.zeros(1, i64), jnp.cumsum(row_len)[:-1]]
+        )
+        valid = jnp.arange(L, dtype=i64)[None, :] < row_len[:, None]
+        tgt = jnp.where(
+            valid, row_start[:, None] + jnp.arange(L, dtype=i64)[None, :], n
+        ).reshape(-1)
+        cbits = max(1, (L - 1).bit_length())
+        if rows is not None and packable and vbits + cbits <= 63:
+            # Record mode, packed: each cell carries ``(value << cbits) |
+            # col`` so one key-only row sort both orders the values and
+            # tells every key which grouped slot it came from
+            # (``row_start[row] + col``) — the provenance gather that routes
+            # payload rows.  Pad cells keep the all-ones value with their
+            # own column in the low bits: they sort after every real key
+            # (ties with a real max-valued key break toward the real key's
+            # smaller column — the same stable tie-break as the fused
+            # engine's provenance lexsort) and land on dropped (``tgt ==
+            # n``) output slots.
+            pad_val = (1 << vbits) - 1
+            cmask = (1 << cbits) - 1
+            cols = jnp.arange(L, dtype=i64)[None, :]
+            pk = jnp.broadcast_to((pad_val << cbits) | cols, (R, L))
+            pk = pk.at[row_of, col_of].set((grouped << cbits) | col_of)
+            spk = jnp.sort(pk, axis=1)
+            sorted_vals = spk >> cbits
+            src = jnp.clip(
+                row_start[:, None] + (spk & cmask), 0, max(n - 1, 0)
+            )
+            stream = _scatter_rows(tgt, sorted_vals, n)
+            src_slot = _scatter_rows(tgt, src, n)
+            stream_rows = rows[order][src_slot]
+        elif rows is not None:
+            # Record mode, wide keys: a stable row argsort keeps the
+            # within-block arrival order on ties — the same tie-break as
+            # the fused engine's provenance lexsort — so payload rows
+            # follow their keys exactly.
+            pad = jnp.iinfo(i64).max
+            mat = jnp.full((R, L), pad, i64).at[row_of, col_of].set(grouped)
+            pmat = jnp.full((R, L), n, i64).at[row_of, col_of].set(
+                jnp.arange(n, dtype=i64)
+            )
+            perm = jnp.argsort(mat, axis=1, stable=True)
+            sorted_vals = jnp.take_along_axis(mat, perm, axis=1)
+            sorted_pos = jnp.take_along_axis(pmat, perm, axis=1)
+            stream = _scatter_rows(tgt, sorted_vals, n)
+            src_slot = _scatter_rows(tgt, sorted_pos, n)
+            stream_rows = rows[order][src_slot]
+        elif use_kernel:
+            from ..kernels import ops  # deferred: only when the backend asks
 
-        pad32 = jnp.iinfo(jnp.int32).max
-        mat32 = jnp.full((R, L), pad32, jnp.int32).at[row_of, col_of].set(
-            grouped.astype(jnp.int32)
-        )
-        sorted32 = ops.sort_rows_padded(mat32, interpret=interpret)
-        stream = jnp.zeros(n + 1, i64).at[tgt].set(
-            sorted32.astype(i64).reshape(-1)
-        )[:n]
-        stream_rows = None
-    else:
-        pad = jnp.iinfo(i64).max
-        mat = jnp.full((R, L), pad, i64).at[row_of, col_of].set(grouped)
-        sorted_vals = jnp.sort(mat, axis=1)
-        stream = jnp.zeros(n + 1, i64).at[tgt].set(sorted_vals.reshape(-1))[:n]
-        stream_rows = None
+            pad32 = jnp.iinfo(jnp.int32).max
+            mat32 = jnp.full((R, L), pad32, jnp.int32).at[row_of, col_of].set(
+                grouped.astype(jnp.int32)
+            )
+            sorted32 = ops.sort_rows_padded(mat32, interpret=interpret)
+            stream = _scatter_rows(tgt, sorted32.astype(i64), n)
+            stream_rows = None
+        else:
+            pad = jnp.iinfo(i64).max
+            mat = jnp.full((R, L), pad, i64).at[row_of, col_of].set(grouped)
+            sorted_vals = jnp.sort(mat, axis=1)
+            stream = _scatter_rows(tgt, sorted_vals, n)
+            stream_rows = None
 
     # -- emission order: slot → emission index --------------------------
-    emit_mask = ranks >= L
-    emit_slot = starts[seg] + ranks - L
-    emit_ord = jnp.cumsum(emit_mask).astype(i64) - 1
-    n_emitted = jnp.maximum(counts - L, 0)
-    n_emit_total = n_emitted.sum()
-    flush_mask = q >= n_emitted[seg_g]
-    flush_ord = n_emit_total + jnp.cumsum(flush_mask).astype(i64) - 1
-    eidx = (
-        jnp.zeros(n + 1, i64)
-        .at[jnp.where(emit_mask, emit_slot, n)]
-        .set(jnp.where(emit_mask, emit_ord, 0))
-        .at[jnp.where(flush_mask, jnp.arange(n, dtype=i64), n)]
-        .set(jnp.where(flush_mask, flush_ord, 0))
-    )[:n]
+    with jax.named_scope("emit"):
+        emit_mask = ranks >= L
+        emit_slot = starts[seg] + ranks - L
+        emit_ord = jnp.cumsum(emit_mask).astype(i64) - 1
+        n_emitted = jnp.maximum(counts - L, 0)
+        n_emit_total = n_emitted.sum()
+        flush_mask = q >= n_emitted[seg_g]
+        flush_ord = n_emit_total + jnp.cumsum(flush_mask).astype(i64) - 1
+        eidx = (
+            jnp.zeros(n + 1, i64)
+            .at[jnp.where(emit_mask, emit_slot, n)]
+            .set(jnp.where(emit_mask, emit_ord, 0))
+            .at[jnp.where(flush_mask, jnp.arange(n, dtype=i64), n)]
+            .set(jnp.where(flush_mask, flush_ord, 0))
+        )[:n]
 
     # -- wire order: packets ship at their last key's emission ----------
-    pkt_j = q // P
-    last_q = jnp.minimum((pkt_j + 1) * P, counts[seg_g]) - 1
-    ship_key = eidx[jnp.clip(starts[seg_g] + last_q, 0, max(n - 1, 0))]
-    if packable:
-        out_perm, _ = _stable_perm(ship_key, n)  # ship index < n: fits
-    else:
-        out_perm = jnp.argsort(ship_key, stable=True)
-    vals_out = stream[out_perm]
-    sid_out = seg_g[out_perm]
-    seq_out = pkt_j[out_perm]
+    with jax.named_scope("ship"):
+        pkt_j = q // P
+        last_q = jnp.minimum((pkt_j + 1) * P, counts[seg_g]) - 1
+        ship_key = eidx[jnp.clip(starts[seg_g] + last_q, 0, max(n - 1, 0))]
+        if packable:
+            out_perm, _ = _stable_perm(ship_key, n)  # ship index < n: fits
+        else:
+            out_perm = jnp.argsort(ship_key, stable=True)
+        vals_out = stream[out_perm]
+        sid_out = seg_g[out_perm]
+        seq_out = pkt_j[out_perm]
 
-    # -- per-key packet ordinal (the next hop's round-robin turn) -------
-    if n:
-        chg = jnp.concatenate([
-            jnp.ones(1, bool),
-            (seq_out[1:] != seq_out[:-1]) | (sid_out[1:] != sid_out[:-1]),
-        ])
-        turn = jnp.cumsum(chg).astype(i64) - 1
-        seg_chg = jnp.concatenate([jnp.ones(1, bool), seg_g[1:] != seg_g[:-1]])
-        desc = jnp.concatenate([jnp.zeros(1, bool), stream[1:] < stream[:-1]])
-        brk = seg_chg | desc
-    else:
-        turn = jnp.zeros(0, i64)
-        brk = jnp.zeros(0, bool)
+        # -- per-key packet ordinal (the next hop's round-robin turn) ---
+        if n:
+            chg = jnp.concatenate([
+                jnp.ones(1, bool),
+                (seq_out[1:] != seq_out[:-1]) | (sid_out[1:] != sid_out[:-1]),
+            ])
+            turn = jnp.cumsum(chg).astype(i64) - 1
+            seg_chg = jnp.concatenate(
+                [jnp.ones(1, bool), seg_g[1:] != seg_g[:-1]]
+            )
+            desc = jnp.concatenate(
+                [jnp.zeros(1, bool), stream[1:] < stream[:-1]]
+            )
+            brk = seg_chg | desc
+        else:
+            turn = jnp.zeros(0, i64)
+            brk = jnp.zeros(0, bool)
 
-    hop = {
-        "vals": vals_out,
-        "seq": seq_out,
-        "sid": sid_out,
-        "turn": turn,
-        "ship": ship_key[out_perm],
-        "counts": counts,
-        "runs": brk.sum().astype(i64),
-        "stream": stream,
-        "brk": brk,
-    }
-    if stream_rows is not None:
-        hop["rows"] = stream_rows[out_perm]
-        hop["stream_rows"] = stream_rows
+        hop = {
+            "vals": vals_out,
+            "seq": seq_out,
+            "sid": sid_out,
+            "turn": turn,
+            "ship": ship_key[out_perm],
+            "counts": counts,
+            "runs": brk.sum().astype(i64),
+            "stream": stream,
+            "brk": brk,
+        }
+        if stream_rows is not None:
+            hop["rows"] = stream_rows[out_perm]
+            hop["stream_rows"] = stream_rows
     return hop
 
 
@@ -338,8 +358,9 @@ def _rr_merge(parts, carry_rows: bool, packable: bool):
 
 def _epoch_program(graph, spec: HopSpec, ranges: np.ndarray,
                    group_ns: tuple, carry_rows: bool, use_kernel: bool,
-                   interpret: bool | None, taps: bool):
-    """Build (or fetch from cache) the jitted whole-epoch program.
+                   interpret: bool | None, taps: bool, tracer=NULL_TRACER):
+    """Fetch the jitted whole-epoch program from the cache, or build it in
+    an ``epoch:build`` span (jax compiles it at its first dispatch).
 
     ``ranges`` participates in the key by value — HopSpec deliberately
     excludes it from comparison, but two specs differing only in their
@@ -351,8 +372,23 @@ def _epoch_program(graph, spec: HopSpec, ranges: np.ndarray,
         taps,
     )
     fn = _PROGRAM_CACHE.get(key)
-    if fn is not None:
-        return fn
+    if fn is None:
+        with tracer.span("epoch:build", cat="epoch", hops=len(graph.nodes)):
+            fn = _build_program(
+                graph, spec, ranges, group_ns, carry_rows, use_kernel,
+                interpret, taps,
+            )
+        if len(_PROGRAM_CACHE) >= _PROGRAM_CACHE_MAX:
+            _PROGRAM_CACHE.pop(next(iter(_PROGRAM_CACHE)))
+        _PROGRAM_CACHE[key] = fn
+    return fn
+
+
+def _build_program(graph, spec: HopSpec, ranges: np.ndarray,
+                   group_ns: tuple, carry_rows: bool, use_kernel: bool,
+                   interpret: bool | None, taps: bool):
+    """The jitted whole-epoch program: each hop in a ``hop_<name>`` named
+    scope, each uplink merge in ``uplink_merge``."""
     import jax
     import jax.numpy as jnp
 
@@ -374,18 +410,22 @@ def _epoch_program(graph, spec: HopSpec, ranges: np.ndarray,
         bounds = jnp.asarray(bounds_np)
         hops = []
         for node in nodes:
-            if node.parents:
-                vals, rows = _rr_merge(
-                    [hops[p] for p in node.parents], carry_rows, vbits > 0
+            with jax.named_scope(f"hop_{node.name}"):
+                if node.parents:
+                    with jax.named_scope("uplink_merge"):
+                        vals, rows = _rr_merge(
+                            [hops[p] for p in node.parents], carry_rows,
+                            vbits > 0,
+                        )
+                else:
+                    vals = ingress_vals[node.group]
+                    rows = ingress_rows[node.group] if carry_rows else None
+                hops.append(
+                    _device_hop(
+                        vals, rows, bounds, S, L, P, vbits, use_kernel,
+                        interpret,
+                    )
                 )
-            else:
-                vals = ingress_vals[node.group]
-                rows = ingress_rows[node.group] if carry_rows else None
-            hops.append(
-                _device_hop(
-                    vals, rows, bounds, S, L, P, vbits, use_kernel, interpret
-                )
-            )
         eg = hops[-1]
         res = {
             "vals": eg["vals"],
@@ -413,11 +453,7 @@ def _epoch_program(graph, spec: HopSpec, ranges: np.ndarray,
             )
         return res
 
-    fn = jax.jit(epoch_fn, donate_argnums=(0, 1))
-    if len(_PROGRAM_CACHE) >= _PROGRAM_CACHE_MAX:
-        _PROGRAM_CACHE.pop(next(iter(_PROGRAM_CACHE)))
-    _PROGRAM_CACHE[key] = fn
-    return fn
+    return jax.jit(epoch_fn, donate_argnums=(0, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +506,8 @@ def run_graph_device(
     pytree) happen per call, counted in :data:`TRANSFER_COUNTS`.
     ``ship_emission=True`` fetches the per-hop taps of an observed run even
     when nothing observes it, so every hop's stats carry their packets'
-    ship indices (the ``run_hop`` contract of :func:`device_hop`).
+    ship indices (the ``run_hop`` contract of :func:`device_hop`).  A
+    ``tracer`` only adds spans: it never selects the observed program.
     """
     tr = tracer or NULL_TRACER
     if int_telemetry or batch.int_meta is not None:
@@ -488,16 +525,13 @@ def run_graph_device(
             graph, batch, spec, "fused",
             tracer=tracer, metrics=metrics, network=network,
         )
+    import jax
+
     from ..core.partition import set_ranges
     from ..kernels.ops import x64_scope
 
     carry_rows = batch.row_index is not None
-    collect = (
-        ship_emission or network is not None or metrics is not None
-        or tr.enabled
-    )
-    ingress = split_by_flow(batch, graph.num_groups)
-    group_ns = tuple(len(g) for g in ingress)
+    collect = ship_emission or network is not None or metrics is not None
     ranges = spec.ranges
     if ranges is None:
         ranges = set_ranges(spec.max_value, spec.num_segments)
@@ -517,17 +551,23 @@ def run_graph_device(
         and vmax < np.iinfo(np.int32).max
     )
 
-    fn = _epoch_program(
-        graph, spec, ranges, group_ns, carry_rows, use_kernel,
-        KERNEL_INTERPRET, collect,
-    )
     with x64_scope():
-        dev_args = _to_device((
-            tuple(np.ascontiguousarray(g.values) for g in ingress),
-            tuple(np.ascontiguousarray(g.row_index) for g in ingress)
-            if carry_rows
-            else (),
-        ))
+        # ``epoch:put`` holds the whole ingress layout: the split into
+        # ingress groups and the one copy of their columns.
+        with tr.span("epoch:put", cat="epoch") as put:
+            ingress = split_by_flow(batch, graph.num_groups)
+            host_args = (
+                tuple(np.ascontiguousarray(g.values) for g in ingress),
+                tuple(np.ascontiguousarray(g.row_index) for g in ingress)
+                if carry_rows
+                else (),
+            )
+            put.set(bytes=nbytes(host_args))
+            dev_args = to_device(host_args)
+        fn = _epoch_program(
+            graph, spec, ranges, tuple(len(g) for g in ingress), carry_rows,
+            use_kernel, KERNEL_INTERPRET, collect, tr,
+        )
         with warnings.catch_warnings():
             # The CPU backend cannot always reuse donated input buffers and
             # says so; donation is a no-op there, not an error.  On real
@@ -535,26 +575,33 @@ def run_graph_device(
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable"
             )
-            res = _fetch(fn(*dev_args))
-
-    n_out = int(res["vals"].size)
-    egress_flow = len(graph.nodes) - 1
-    stats = [
-        _stats_from_device(node.name, res["counts"][i], res["runs"][i], L)
-        for i, node in enumerate(graph.nodes)
-    ]
-    delivery = DeviceDelivery(
-        res["vals"],
-        np.full(n_out, egress_flow, dtype=np.int64),
-        res["seq"],
-        res["sid"],
-        epoch=batch.epoch,
-        row_index=res.get("rows"),
-        grouped_values=res["stream"],
-        grouped_rows=res.get("stream_rows"),
-        seg_counts=np.asarray(res["counts"][-1], dtype=np.int64),
-        run_flags=res["brk"],
-    )
+            with tr.span("epoch:device", cat="epoch"):
+                dev_res = jax.block_until_ready(fn(*dev_args))
+        # ``epoch:fetch`` holds the result's copy and its assembly into the
+        # egress wire batch on the host.
+        with tr.span("epoch:fetch", cat="epoch", bytes=nbytes(dev_res)):
+            res = fetch(dev_res)
+            stats = [
+                _stats_from_device(
+                    node.name, res["counts"][i], res["runs"][i], L
+                )
+                for i, node in enumerate(graph.nodes)
+            ]
+            delivery = DeviceDelivery(
+                res["vals"],
+                np.full(
+                    int(res["vals"].size), len(graph.nodes) - 1,
+                    dtype=np.int64,
+                ),
+                res["seq"],
+                res["sid"],
+                epoch=batch.epoch,
+                row_index=res.get("rows"),
+                grouped_values=res["stream"],
+                grouped_rows=res.get("stream_rows"),
+                seg_counts=np.asarray(res["counts"][-1], dtype=np.int64),
+                run_flags=res["brk"],
+            )
     if not collect:
         return delivery, stats
 
@@ -583,10 +630,6 @@ def run_graph_device(
             inp = merge_round_robin_batches([outs[p] for p in node.parents])
         else:
             inp = ingress[node.group]
-        with tr.span(
-            f"hop:{node.name}", cat="hop", keys=len(inp)
-        ) as hop_sp:
-            hop_sp.set(keys_out=len(out))
         pstarts = out.packet_starts()
         stats[i] = dataclasses.replace(
             stats[i], ship_emission=np.asarray(tap["ship"])[pstarts]

@@ -45,7 +45,6 @@ import dataclasses
 
 import numpy as np
 
-from repro.obs.metrics import MetricsRegistry
 from repro.obs.telemetry import int_summary
 from repro.obs.trace import NULL_TRACER
 
@@ -214,14 +213,17 @@ def run_pipeline(
 
     Observability (all opt-in and output-transparent — the sorted stream,
     passes, and epoch structure are byte-identical instrumented or not):
-    ``tracer`` (a :class:`repro.obs.Tracer`) records the full span
-    hierarchy (pipeline → epoch → hop → stages; server/egress lanes);
-    ``metrics`` (a :class:`repro.obs.MetricsRegistry`) accumulates the
-    dataplane counters/gauges/histograms — when a recording tracer is given
-    without a registry, one is created so the snapshot always lands in
+    every span (pipeline → ``pipeline:ingress`` → epoch → hop or
+    ``epoch:*`` stages → ``server<s>:*``/merge lanes → ``pipeline:result``)
+    reaches a running JAX profiler's trace, with or without a ``tracer``;
+    ``tracer`` (a :class:`repro.obs.Tracer`) also keeps them for export and
+    changes nothing else about the run.  ``metrics`` (a
+    :class:`repro.obs.MetricsRegistry`) accumulates the dataplane
+    counters/gauges/histograms, whose snapshot lands in
     ``PipelineResult.telemetry``; ``int_telemetry=True`` stamps INT-style
     per-hop metadata columns onto the wire (``fused`` engine only), exposed
     on ``result.delivered.int_meta`` and summarized in the telemetry dict.
+    Telemetry comes from these two alone.
 
     ``network`` (a :class:`~repro.net.timing.NetworkConfig`) runs the fabric
     under the per-link timing model: every link gets a latency / bandwidth /
@@ -290,14 +292,7 @@ def run_pipeline(
     fault_counters = {"dead": 0, "degraded": 0, "range_fallbacks": 0}
 
     tr = tracer or NULL_TRACER
-    if metrics is None and tr.enabled:
-        # A recording tracer implies an observed run: build a registry so
-        # the snapshot always lands in ``PipelineResult.telemetry``.
-        metrics = MetricsRegistry()
-
     with tr.span("pipeline", cat="pipeline", n=int(values.size)):
-        flows = split_flows(values, num_flows, payload_size)
-        arrivals = interleave_batch(flows, interleave_mode, seed=seed)
         nbits = 0
         if payload is not None:
             payload = np.asarray(payload)
@@ -312,21 +307,27 @@ def run_pipeline(
                     f"cannot pack {values.size} payload rows next to keys "
                     f"up to {max_value} in 63 bits"
                 )
-            # Thread each key's input row through the same shard split and
-            # interleave schedule the keys took (the schedule depends only
-            # on flow sizes and the seed), so the row column lands on its
-            # key's arrival row.  The payload table itself stays put until
-            # the one egress gather.
-            rows = interleave_batch(
-                split_flows(
-                    np.arange(values.size, dtype=np.int64),
-                    num_flows,
-                    payload_size,
-                ),
-                interleave_mode,
-                seed=seed,
-            )
-            arrivals = arrivals.with_row_index(rows.values)
+        with tr.span(
+            "pipeline:ingress", cat="pipeline", keys=int(values.size)
+        ):
+            flows = split_flows(values, num_flows, payload_size)
+            arrivals = interleave_batch(flows, interleave_mode, seed=seed)
+            if payload is not None:
+                # Thread each key's input row through the same shard split
+                # and interleave schedule the keys took (the schedule
+                # depends only on flow sizes and the seed), so the row
+                # column lands on its key's arrival row.  The payload table
+                # itself stays put until the one egress gather.
+                rows = interleave_batch(
+                    split_flows(
+                        np.arange(values.size, dtype=np.int64),
+                        num_flows,
+                        payload_size,
+                    ),
+                    interleave_mode,
+                    seed=seed,
+                )
+                arrivals = arrivals.with_row_index(rows.values)
 
         def _run_topology(ranges: np.ndarray, batch: WireBatch, epoch: int = 0):
             ef = fault_plan.at_epoch(epoch) if fault_plan is not None else None
@@ -516,54 +517,57 @@ def run_pipeline(
             out = out >> nbits
             sorted_payload = payload[row_order]
 
+        telemetry = None
+        if metrics is not None or delivered.int_meta is not None:
+            telemetry = metrics.snapshot() if metrics is not None else {}
+            if delivered.int_meta is not None:
+                telemetry["int"] = int_summary(delivered.int_meta)
+
+        with tr.span("pipeline:result", cat="pipeline"):
+            # Reorder-buffer-corrected per-segment streams, for multiset
+            # invariants.  (jitter permutes packets; segment_streams gives
+            # raw arrival order, which is fine — invariants are
+            # multiset-level.)
+            seg_ms = segment_streams_batch(delivered, eff_segments)
+            result = PipelineResult(
+                output=out,
+                passes=passes,
+                hop_stats=hop_stats,
+                segment_multisets=seg_ms,
+                max_reorder_depth=pool.max_reorder_depth,
+                server_seconds=pool.makespan_seconds,
+                n=int(values.size),
+                range_mode=mode_str,
+                num_epochs=len(ranges_history),
+                ranges_history=ranges_history,
+                engine=engine,
+                delivered=delivered,
+                num_servers=num_servers,
+                merge_backend=merge_backend,
+                per_server_seconds=list(pool.per_server_seconds),
+                pool_merge_seconds=pool.merge_seconds,
+                server_keys=pool.server_keys,
+                server_imbalance=pool.server_imbalance,
+                telemetry=telemetry,
+                network=net_report,
+                dup_packets_dropped=pool.dup_packets_dropped,
+                spilled_packets=pool.spilled_packets,
+                spilled_keys=pool.spilled_keys,
+                sorted_payload=sorted_payload,
+                payload_row_order=row_order,
+                fault_hops_dead=fault_counters["dead"],
+                fault_hops_degraded=fault_counters["degraded"],
+                servers_failed_over=pool.servers_failed_over,
+                range_fallbacks=fault_counters["range_fallbacks"],
+            )
+
     if verify:
         np.testing.assert_array_equal(out, np.sort(values))
         if payload is not None:
             np.testing.assert_array_equal(
                 row_order, np.argsort(values, kind="stable")
             )
-
-    telemetry = None
-    if metrics is not None or delivered.int_meta is not None:
-        telemetry = metrics.snapshot() if metrics is not None else {}
-        if delivered.int_meta is not None:
-            telemetry["int"] = int_summary(delivered.int_meta)
-
-    # Reorder-buffer-corrected per-segment streams, for multiset invariants.
-    # (jitter permutes packets; segment_streams gives raw arrival order,
-    # which is fine — invariants are multiset-level.)
-    seg_ms = segment_streams_batch(delivered, eff_segments)
-    return PipelineResult(
-        output=out,
-        passes=passes,
-        hop_stats=hop_stats,
-        segment_multisets=seg_ms,
-        max_reorder_depth=pool.max_reorder_depth,
-        server_seconds=pool.makespan_seconds,
-        n=int(values.size),
-        range_mode=mode_str,
-        num_epochs=len(ranges_history),
-        ranges_history=ranges_history,
-        engine=engine,
-        delivered=delivered,
-        num_servers=num_servers,
-        merge_backend=merge_backend,
-        per_server_seconds=list(pool.per_server_seconds),
-        pool_merge_seconds=pool.merge_seconds,
-        server_keys=pool.server_keys,
-        server_imbalance=pool.server_imbalance,
-        telemetry=telemetry,
-        network=net_report,
-        dup_packets_dropped=pool.dup_packets_dropped,
-        spilled_packets=pool.spilled_packets,
-        spilled_keys=pool.spilled_keys,
-        sorted_payload=sorted_payload,
-        payload_row_order=row_order,
-        fault_hops_dead=fault_counters["dead"],
-        fault_hops_degraded=fault_counters["degraded"],
-        servers_failed_over=pool.servers_failed_over,
-        range_fallbacks=fault_counters["range_fallbacks"],
-    )
+    return result
 
 
 def plain_stream_sort(

@@ -467,7 +467,7 @@ class StreamingServer:
                         outs.append(
                             merge_runs_flat(
                                 arena.keys, starts, lengths,
-                                tracer=self._tr if tr.enabled else None,
+                                tracer=tr,
                                 tid=self.lane,
                             )
                         )
@@ -492,7 +492,7 @@ class StreamingServer:
                 out = (
                     merge_runs_batched(
                         outs,
-                        tracer=self._tr if tr.enabled else None,
+                        tracer=tr,
                         tid=self.lane,
                     )
                     if self._arenas is not None

@@ -2,10 +2,12 @@
 
 Three layers answer three different questions about a pipeline run:
 
-* :mod:`repro.obs.trace` — *where did the time go?*  A hierarchical span
-  :class:`Tracer` (epoch → hop → route/rank/sort/emit stages → server
-  ingest/merge/tournament levels) with a zero-overhead :class:`NullTracer`
-  default and Chrome-trace-event JSON export viewable in Perfetto.
+* :mod:`repro.obs.trace` — *where did the time go?*  Hierarchical spans
+  (ingress → epoch → hop or epoch stages → server ingest/merge/tournament
+  levels → result) that reach a running JAX profiler's trace on the
+  device's clock; a recording :class:`Tracer` also keeps them for
+  Chrome-trace-event JSON export viewable in Perfetto, and the
+  :class:`NullTracer` default keeps nothing.
 * :mod:`repro.obs.metrics` — *what did the dataplane's state look like?*
   A :class:`MetricsRegistry` of counters/gauges/histograms/series (keys
   in/out per hop, segment occupancy, run-length histogram, reorder-depth
@@ -16,7 +18,7 @@ Three layers answer three different questions about a pipeline run:
   rank ticks) stamped onto the ``WireBatch`` and riding the wire to
   egress, mirroring how programmable switches export state in-band.
 
-All instrumentation is opt-in: the dataplane's default arguments are
+Recording is opt-in: the dataplane's default arguments are
 ``tracer=None`` / ``metrics=None`` / ``int_telemetry=False``, and the
 pipeline's output is byte-identical with observability on or off (gated
 by ``tests/test_obs_transparency.py`` and the CI overhead gate).

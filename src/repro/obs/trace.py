@@ -1,22 +1,27 @@
-"""Hierarchical span tracer with Chrome-trace-event export.
+"""Hierarchical span tracer, on the profiler's clock, with Chrome export.
 
-The dataplane's question is always *where did this epoch's time go* — which
-hop, which stage inside the hop (route/rank/sort/emit), which server, which
-merge level.  A :class:`Tracer` answers it with nested **spans**: context
-managers that record wall-clock intervals onto a flat event list, carrying a
-category, a lane (Chrome ``tid`` — servers get their own lanes so the pool's
-makespan reads off the timeline), and free-form args.  :meth:`Tracer.dump`
-writes the standard Chrome trace-event JSON (``{"traceEvents": [...]}``),
-loadable in Perfetto / ``chrome://tracing`` — span nesting is implied by
-timestamp containment within a lane, exactly how those tools render it.
+The dataplane's question is always *where did this job's time go* — which
+layer, which stage of the epoch, which server, which merge.  Every span
+opens a ``jax.profiler.TraceAnnotation`` under its name and args, so when
+the JAX profiler runs, the program's spans and the device's operations
+share one clock and one trace: an idle gap on the device falls inside a
+named span of the host.  With no profiler running an annotation costs
+about a microsecond.  ``jax`` is imported at the first span, not before.
+
+A recording :class:`Tracer` also keeps its spans in memory: context
+managers that record wall-clock intervals onto a flat event list, carrying
+a category, a lane (Chrome ``tid`` — servers get their own lanes so the
+pool's makespan reads off the timeline), and free-form args.
+:meth:`Tracer.dump` writes the standard Chrome trace-event JSON
+(``{"traceEvents": [...]}``), loadable in Perfetto / ``chrome://tracing``.
+A tracer observes and never steers: the program runs the same code, and
+the device the same compiled programs, with any tracer or none.
 
 The default everywhere is :data:`NULL_TRACER`, a :class:`NullTracer` whose
-``span()`` returns one shared, stateless no-op context manager — enabling
-the plumbing costs the uninstrumented pipeline nothing (the overhead of a
-*recording* tracer is measured by ``benchmarks/net_bench.py`` and gated
-≤ 5% in CI).  Both tracers also serve as the repo's **single wall-clock
-source**: :meth:`timed` always measures (two ``perf_counter`` calls, even on
-the null tracer) and exposes ``.seconds``, which is how the egress pool's
+``span()`` opens the annotation and records nothing.  Both tracers also
+serve as the repo's **single wall-clock source**: :meth:`timed` always
+measures (two ``perf_counter`` calls, even on the null tracer), exposes
+``.seconds`` and opens no annotation, which is how the egress pool's
 ``per_server_seconds``/``makespan`` and the switchless baseline keep their
 values with tracing off while sharing one timing code path with tracing on.
 """
@@ -45,23 +50,40 @@ class Span:
         return self.dur
 
 
-class _NullSpan:
-    """Shared no-op span: the zero-overhead path of :class:`NullTracer`."""
+#: ``jax.profiler.TraceAnnotation``, imported at the first span.
+_ANNOTATION = None
 
-    __slots__ = ()
+
+def _annotation(name: str, args: dict):
+    """A profiler annotation of ``name`` and ``args``."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **args)
+
+
+class _NullSpan:
+    """The null tracer's span: a profiler annotation, recorded nowhere else."""
+
+    __slots__ = ("_ann",)
     seconds = 0.0
 
+    def __init__(self, name: str, args: dict) -> None:
+        self._ann = _annotation(name, args)
+
     def __enter__(self) -> "_NullSpan":
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
         return False
 
     def set(self, **args) -> None:
-        """Late-annotation no-op (the recording span attaches args)."""
-
-
-_NULL_SPAN = _NullSpan()
+        """Attach args discovered mid-span to the annotation."""
+        self._ann.set_metadata(**args)
 
 
 class _Timed:
@@ -92,17 +114,17 @@ class _Timed:
 class NullTracer:
     """The default tracer: records nothing, costs (almost) nothing.
 
-    ``span()`` hands back one shared stateless context manager;  ``timed()``
+    ``span()`` opens a profiler annotation and keeps no record;  ``timed()``
     still measures wall-clock (it is the repo's timing primitive) but keeps
     no record;  ``instant()`` is a no-op.  ``enabled`` lets hot paths skip
-    building argument dicts entirely.
+    building argument dicts that only a recording tracer keeps.
     """
 
     enabled = False
     clock = staticmethod(time.perf_counter)
 
     def span(self, name: str, cat: str = "", tid: int = 0, **args):
-        return _NULL_SPAN
+        return _NullSpan(name, args)
 
     def timed(self, name: str, cat: str = "", tid: int = 0, **args):
         return _Timed(self.clock)
@@ -116,27 +138,34 @@ NULL_TRACER = NullTracer()
 
 
 class _RecordingSpan:
-    """Context manager that appends a :class:`Span` to its tracer on exit."""
+    """Context manager that appends a :class:`Span` to its tracer on exit,
+    inside a profiler annotation when ``annotate`` (``span``, not
+    ``timed``)."""
 
     __slots__ = ("_tracer", "_name", "_cat", "_tid", "_args", "_t0", "_depth",
-                 "seconds")
+                 "_ann", "seconds")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, tid: int,
-                 args: dict) -> None:
+                 args: dict, annotate: bool) -> None:
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._tid = tid
         self._args = args
+        self._ann = _annotation(name, args) if annotate else None
         self.seconds = 0.0
 
     def __enter__(self) -> "_RecordingSpan":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._depth = self._tracer._enter(self._tid)
         self._t0 = self._tracer.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = self._tracer.clock()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self.seconds = t1 - self._t0
         self._tracer._exit(self._tid)
         self._tracer.spans.append(
@@ -155,6 +184,8 @@ class _RecordingSpan:
     def set(self, **args) -> None:
         """Attach args discovered mid-span (e.g. counts known after work)."""
         self._args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
 
 class Tracer:
@@ -165,10 +196,14 @@ class Tracer:
     renders as parallel tracks.  The span hierarchy the pipeline emits::
 
         pipeline
-        └─ epoch:<e>
-           └─ hop:<name>             (cat="hop", one per fabric node)
-              ├─ route / rank / sort / emit   (cat="stage")
-              └─ stats / packetize           (cat="stage")
+        ├─ pipeline:ingress          (flows split and interleaved)
+        ├─ epoch:<e>
+        │  ├─ hop:<name>             (cat="hop", per-hop engines)
+        │  │  ├─ route / rank / sort / emit   (cat="stage")
+        │  │  └─ stats / packetize           (cat="stage")
+        │  └─ epoch:build / epoch:put / epoch:device / epoch:fetch
+        │                            (cat="epoch", compiled device epoch)
+        └─ pipeline:result           (per-segment streams, the result)
         server<s>:ingest             (cat="server", lane 1+s)
         └─ ladder:L<d>               (cat="server", eager k-way merges)
         server<s>:finish             (cat="server", lane 1+s)
@@ -201,11 +236,13 @@ class Tracer:
     # -- public API -----------------------------------------------------
     def span(self, name: str, cat: str = "", tid: int = 0, **args):
         """Open a recorded span; use as a context manager."""
-        return _RecordingSpan(self, name, cat, tid, args)
+        return _RecordingSpan(self, name, cat, tid, args, True)
 
     def timed(self, name: str, cat: str = "", tid: int = 0, **args):
-        """Like :meth:`span`; the name marks it as a results timing source."""
-        return _RecordingSpan(self, name, cat, tid, args)
+        """Like :meth:`span`, but measure-only on the profiler's side: the
+        interval it times is a results field, and the spans inside it
+        already annotate the work."""
+        return _RecordingSpan(self, name, cat, tid, args, False)
 
     def instant(self, name: str, cat: str = "", tid: int = 0, **args) -> None:
         """A zero-duration event (control-plane handoffs, faults)."""

@@ -126,23 +126,98 @@ def test_one_transfer_each_way_per_epoch():
     _assert_batches_equal(out, ref)
 
 
-def test_observed_mode_still_one_fetch():
-    from repro.obs import Tracer
+def test_observed_mode_still_one_fetch(monkeypatch):
+    from repro.net import device_epoch
+    from repro.obs import MetricsRegistry, Tracer
 
     vals = SCENARIOS["drifting"](N, seed=5)
     flows = split_flows(vals, 4, 32)
     batch = interleave_batch(flows, "round_robin", seed=0)
     spec = HopSpec(SEGS, LENGTH, max_value=scenario_max_value("drifting"))
     graph = leaf_spine_graph(4)
+    ref, rstats = run_graph(graph, batch, spec, engine="fused")
+    monkeypatch.setattr(device_epoch, "_PROGRAM_CACHE", {})
+
+    # A recording tracer alone runs the program no tracer runs: no taps,
+    # no hop spans, one fetch.
     reset_transfer_counts()
     tr = Tracer()
-    out, stats = run_graph(graph, batch, spec, engine="device", tracer=tr)
+    out, _ = run_graph(graph, batch, spec, engine="device", tracer=tr)
     assert TRANSFER_COUNTS == {"to_device": 1, "to_host": 1}
-    assert tr.find(cat="hop"), "replay should emit hop spans"
-    ref, rstats = run_graph(graph, batch, spec, engine="fused")
+    assert not tr.find(cat="hop")
+    assert [s.name for s in tr.find(cat="epoch")] == [
+        "epoch:put", "epoch:build", "epoch:device", "epoch:fetch",
+    ]
+    (key,) = device_epoch._PROGRAM_CACHE
+    assert key[-1] is False  # taps
+    untraced, _ = run_graph(graph, batch, spec, engine="device")
+    assert list(device_epoch._PROGRAM_CACHE) == [key]
+    _assert_batches_equal(out, ref)
+    _assert_batches_equal(untraced, ref)
+
+    # A metrics registry still observes the run: the taps ride the same
+    # single fetch.
+    reset_transfer_counts()
+    out, stats = run_graph(
+        graph, batch, spec, engine="device", metrics=MetricsRegistry()
+    )
+    assert TRANSFER_COUNTS == {"to_device": 1, "to_host": 1}
+    assert [k[-1] for k in device_epoch._PROGRAM_CACHE] == [False, True]
     _assert_batches_equal(out, ref)
     for sd, sf in zip(stats, rstats):
         np.testing.assert_array_equal(sd.ship_emission, sf.ship_emission)
+
+
+def test_arena_tournaments_count_their_transfers():
+    """Each run-arena tournament copies its matrix to the device and back
+    through the counted choke points, and its span carries those bytes."""
+    from repro.obs import Tracer
+
+    vals = np.random.default_rng(4).integers(0, 1 << 15, size=40_000)
+    tr = Tracer()
+    reset_transfer_counts()
+    res = run_pipeline(
+        vals, engine="device", merge_backend="arena", tracer=tr,
+        num_segments=4, segment_length=16, max_value=1 << 15, num_flows=1,
+        payload_size=64,
+    )
+    np.testing.assert_array_equal(res.output, np.sort(vals))
+    tours = [s for s in tr.spans if s.name.startswith("tournament:b")]
+    assert tours
+    assert TRANSFER_COUNTS == {
+        "to_device": 1 + len(tours), "to_host": 1 + len(tours),
+    }
+    for sp in tours:
+        B = int(sp.name.removeprefix("tournament:b"))
+        rows = max(2, 1 << (sp.args["runs"] - 1).bit_length())
+        assert sp.args["bytes"] == 2 * rows * B * 2  # uint16 keys
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+def test_epoch_program_names_its_stages():
+    """Every hop, stage and uplink merge of the epoch is a named scope, so
+    a profiler trace's ``tf_op`` of each device operation names it."""
+    import jax
+
+    from repro.core.partition import set_ranges
+    from repro.kernels.ops import x64_scope
+    from repro.net.device_epoch import _epoch_program
+
+    max_value = scenario_max_value("drifting")
+    spec = HopSpec(
+        SEGS, LENGTH, max_value, set_ranges(max_value, SEGS), payload_size=32
+    )
+    graph = leaf_spine_graph(2)
+    fn = _epoch_program(
+        graph, spec, spec.ranges, (500, 500), False, False, None, False
+    )
+    arg = jax.ShapeDtypeStruct((500,), np.int64)
+    with x64_scope():
+        text = fn.lower((arg, arg), ()).as_text(debug_info=True)
+    for hop in ("leaf0", "leaf1", "spine"):
+        for stage in ("route", "rank", "block_sort", "emit", "ship"):
+            assert f'"jit(epoch_fn)/hop_{hop}/{stage}/' in text, (hop, stage)
+    assert '"jit(epoch_fn)/hop_spine/uplink_merge/' in text
 
 
 # -- payload records ----------------------------------------------------

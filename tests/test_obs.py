@@ -81,11 +81,10 @@ def test_chrome_trace_export_is_valid_and_sorted(tmp_path):
 def test_null_tracer_records_nothing_but_timed_still_measures():
     tr = NullTracer()
     assert tr is not NULL_TRACER and not tr.enabled
-    span = tr.span("x", cat="hop")
-    assert span is tr.span("y")  # one shared stateless no-op
-    with span as sp:
-        sp.set(anything=1)
+    with tr.span("x", cat="hop", keys=3) as sp:
+        sp.set(anything=1)  # reaches the profiler annotation only
     assert sp.seconds == 0.0
+    assert not hasattr(tr, "spans")  # keeps no record
     with tr.timed("wall") as t:
         sum(range(1000))
     assert t.seconds > 0  # the single wall-clock source keeps working
@@ -254,6 +253,80 @@ def test_pipeline_telemetry_snapshot_counters_balance(vals):
 
 def test_pipeline_without_instrumentation_has_no_telemetry(vals):
     assert _run(vals).telemetry is None
+
+
+@pytest.mark.parametrize("engine", ["fused", "device"])
+def test_recording_tracer_builds_no_registry(vals, engine):
+    """A tracer observes: telemetry comes from ``metrics=`` or INT only."""
+    tr = Tracer()
+    res = _run(vals, tracer=tr, engine=engine)
+    assert res.telemetry is None
+    assert "pipeline:ingress" in {s.name for s in tr.spans}
+
+
+#: The spans of one ``engine="device"``, ``merge_backend="arena"`` job, and
+#: the arg each carries.
+JOB_SPANS = {
+    "pipeline": "n",
+    "pipeline:ingress": "keys",
+    "epoch:build": "hops",
+    "epoch:put": "bytes",
+    "epoch:device": None,
+    "epoch:fetch": "bytes",
+    "server0:ingest": "keys",
+    "server0:finish": None,
+    "merge:seg0": "keys",
+    "tournament:b": "bytes",
+    "winners": "runs",
+    "pipeline:result": None,
+}
+
+
+def test_spans_reach_the_profiler_trace(tmp_path, monkeypatch):
+    """With no tracer passed, a profiled job's trace holds every span of
+    the chip path, nested in the annotation around the job, with its args
+    (as the benchmark's trace reader sees them)."""
+    import importlib
+    from pathlib import Path
+
+    import jax
+
+    from repro.net import device_epoch
+
+    monkeypatch.syspath_prepend(Path(__file__).resolve().parents[1] / "bench")
+    tracefile = importlib.import_module("tracefile")
+
+    monkeypatch.setattr(device_epoch, "_PROGRAM_CACHE", {})
+    keys = np.random.default_rng(3).integers(0, 1 << 15, size=40_000)
+    kw = dict(
+        num_segments=4, segment_length=16, max_value=1 << 15, num_flows=1,
+        payload_size=64, engine="device", backend="numpy",
+        merge_backend="arena",
+    )
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation(tracefile.JOB_SPAN):
+            res = run_pipeline(keys, **kw)
+    finally:
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(res.output, np.sort(keys))
+    trace = tracefile.load(tracefile.find_trace(tmp_path))
+    (job,) = [ev for ev in trace.python if tracefile._is_job(ev)]
+    found = {}
+    for ev in trace.python:
+        name = ev.args.get("long_name", ev.name)
+        for want in JOB_SPANS:
+            if name == want or (want.endswith(":b") and name.startswith(want)):
+                assert job.start <= ev.start and ev.end <= job.end, name
+                found.setdefault(want, ev)
+    assert set(found) == set(JOB_SPANS)
+    for want, arg in JOB_SPANS.items():
+        if arg is not None:
+            assert int(found[want].args[arg]) > 0, want
+    assert int(found["pipeline"].args["n"]) == keys.size
+    # The fetch carries the egress columns: vals, seq, sid, stream (int64)
+    # and the run-break flags (bool), plus the per-hop scalars.
+    assert int(found["epoch:fetch"].args["bytes"]) >= 33 * keys.size
 
 
 def test_int_meta_depth_matches_fabric_depth(vals):

@@ -23,7 +23,7 @@ except ImportError:
 
 from repro.data import SCENARIOS, TRACES, scenario_max_value, trace_max_value
 from repro.net import run_pipeline
-from repro.obs import Tracer
+from repro.obs import MetricsRegistry, Tracer
 
 TOPO_CASES = [
     ("single", {}),
@@ -71,7 +71,7 @@ def _assert_transparent(vals, maxv, topo, topo_kw, **over):
     # int_telemetry only where the fused engine runs (the default)
     int_ok = over.get("engine", "fused") == "fused"
     got = _run(vals, maxv, topo, topo_kw, tracer=tr,
-               int_telemetry=int_ok, **over)
+               metrics=MetricsRegistry(), int_telemetry=int_ok, **over)
     np.testing.assert_array_equal(ref.output, got.output)
     np.testing.assert_array_equal(ref.delivered.values, got.delivered.values)
     np.testing.assert_array_equal(
