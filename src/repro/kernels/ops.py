@@ -32,7 +32,10 @@ from .flash_attention import flash_attention as _flash_attention
 
 #: Calls of the sort/merge entry points by ``"<op>:<lowering>:<key
 #: dtype>"``.  A call made while a larger program is traced (the device
-#: epoch's block sort) counts once per trace, not once per execution.
+#: epoch's block sort) counts once per trace, not once per execution.  The
+#: device epoch also counts each hop it traces, as
+#: ``"epoch_hop:scatter_free:int64"`` (the hop's stage math lowers without
+#: a scatter).
 LOWERING_COUNTS: collections.Counter = collections.Counter()
 
 

@@ -16,16 +16,25 @@ ingest and leave once at egress; the transfer counters below prove it.
 Stage math (per hop, all static-shape jnp over ``n`` arrival keys):
 
 * route: ``searchsorted`` over the shared range bounds (the parse cascade);
-* rank: one stable argsort by segment + a scatter — grouping permutation,
-  per-segment counts/starts, per-arrival ranks;
+* rank: one stable (packed key-only) sort by segment — the grouping
+  permutation; per-segment starts and counts are searches into the sorted
+  segment column, and each slot's in-segment position follows from them;
 * block sort: every segment's L-blocks laid out as rows of one padded
-  ``(n//L + S, L)`` matrix.  Bare keys sort with ``jnp.sort`` — or with the
-  Pallas bitonic kernel (:func:`repro.kernels.ops.sort_rows_padded`) under
-  the same fallback rules as the per-hop fused path; record mode uses a
-  stable row argsort so each key's payload row follows it through the sort;
-* emission order: the slot→emission-index map built by two predicated
-  scatters (per-arrival emissions in arrival order, then the flush tails in
-  segment-major order — exactly Alg. 3's two flush passes);
+  ``(n//L + S, L)`` matrix.  Each segment's slots move there, and back,
+  by one per-segment shift (static slices selected by segment, or one
+  gather with a dense index when there are many segments), and every row's
+  length is known in closed form.  Bare keys sort with ``jnp.sort`` — or
+  with the Pallas bitonic kernel (:func:`repro.kernels.ops.sort_rows_padded`)
+  under the same fallback rules as the per-hop fused path; record mode uses
+  a stable row argsort so each key's payload row follows it through the
+  sort;
+* emission order: the slot→emission-index map.  Only a segment's first L
+  arrivals do not emit on arrival, so an arrival's emission ordinal is its
+  index less the number of those before it (one search), and slot ``p`` is
+  emitted by the arrival at slot ``p + L``: a static shift by L.  The
+  flush tails follow in segment-major order — exactly Alg. 3's two flush
+  passes.  No stage of the hop scatters (``.at[]`` or ``bincount``); each
+  traced hop says so in :data:`repro.kernels.ops.LOWERING_COUNTS`;
 * wire order: a packet ships when its last key is emitted, vectorized as a
   stable argsort of per-key ship indices (all keys of a packet share their
   packet's ship index, so the stable sort reproduces the fused engine's
@@ -139,13 +148,164 @@ def _stable_perm(key, n: int):
     return packed & ((1 << ibits) - 1), packed >> ibits
 
 
-def _scatter_rows(tgt, mat, n: int):
-    """The sorted ``(R, L)`` matrix's cells scattered to their grouped
-    slots ``tgt`` (pad cells aim at slot ``n``, which is dropped)."""
+#: Largest segment count whose block layout moves by per-segment static
+#: slices; past it one gather with a dense index does the same move.  The
+#: slices read the stream once per segment, the gather pays a fixed price;
+#: the slices' compile time grows with S (TPU v5e timings: PERF.md,
+#: Findings).
+_SLICED_SEGMENTS_MAX = 256
+
+#: Largest table of first arrivals (S*L) that the emission search compares
+#: every arrival with; past it the search sorts (TPU v5e timings:
+#: PERF.md, Findings).
+_COMPARE_ALL_MAX = 1 << 13
+
+
+def _index_dtype(size: int):
+    """int32 where every index below ``size`` fits, else int64."""
     import jax.numpy as jnp
 
-    out = jnp.zeros(n + 1, jnp.int64).at[tgt].set(mat.reshape(-1))
-    return out[:n]
+    return jnp.int32 if size < 1 << 31 else jnp.int64
+
+
+class _BlockRows:
+    """Every segment's L-blocks as the rows of one padded ``(R, L)`` matrix,
+    ``R = n // L + S``, and the moves between it and the grouped layout.
+
+    Grouped slot ``p`` of segment ``s`` (in-segment position ``q``) sits at
+    cell ``(blk_base[s] + q // L, q % L)``: flat cell ``p + d[s]`` with the
+    per-segment shift ``d = blk_base * L - starts``.  Both moves are copies
+    by that shift (``S`` static slices selected by segment, or one gather
+    for many segments), and each row's segment, start and length follow in
+    closed form from ``cumsum(nblk)``.
+    """
+
+    def __init__(self, seg_g, counts, starts, S: int, L: int):
+        import jax.numpy as jnp
+
+        i64 = jnp.int64
+        n = int(seg_g.shape[0])
+        self.n, self.S, self.L, self.R = n, S, L, n // L + S
+        self.idx = _index_dtype(self.R * L)
+        self.seg_g = seg_g
+        nblk = -(-counts // L)
+        cum_blk = jnp.cumsum(nblk)
+        blk_base = cum_blk - nblk
+        # Row r holds segment s_row[r]; rows past the used ones get S, whose
+        # table entries below are 0 (an empty segment).
+        r = jnp.arange(self.R, dtype=i64)
+        s_row = jnp.searchsorted(cum_blk, r, side="right")
+        pad0 = jnp.zeros(1, i64)
+        self.base, self.starts, self.counts = blk_base * L, starts, counts
+        self.d = self.base - starts
+        self.row_start = r * L - jnp.concatenate([self.d, pad0])[s_row]
+        self.row_len = jnp.clip(
+            jnp.concatenate([counts, pad0])[s_row]
+            - (r - jnp.concatenate([blk_base, pad0])[s_row]) * L,
+            0, L,
+        )
+        self.cols = jnp.arange(L, dtype=i64)[None, :]
+        self.valid = self.cols < self.row_len[:, None]
+
+    def slots(self):
+        """Each cell's grouped slot (``n`` on pad cells)."""
+        import jax.numpy as jnp
+
+        return jnp.where(
+            self.valid, self.row_start[:, None] + self.cols, self.n
+        )
+
+    def to_rows(self, x, pad):
+        """The grouped column ``x`` laid out as the ``(R, L)`` matrix, pad
+        cells holding ``pad``."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        n, S, L, R = self.n, self.S, self.L, self.R
+        if not n:  # a hop with no arrivals (an ingress group with no flow)
+            return jnp.full((R, L), pad, x.dtype)
+        if S > _SLICED_SEGMENTS_MAX:
+            src = jnp.clip(self.row_start[:, None] + self.cols, 0, n - 1)
+            return jnp.where(self.valid, x[src], pad)
+        # Cell t of segment s holds x[t - d[s]]: a slice of x front-padded
+        # by the largest shift, (S - 1) * (L - 1).  Segments start at cell
+        # base[s] in order, so the last one started by t owns it (an empty
+        # segment is overridden by the next), and t is valid below that
+        # segment's base + count.  All flat, so the selects fuse into one
+        # pass; the matrix shape comes once, at the end.
+        D = (S - 1) * (L - 1)
+        xp = lax.pad(x, jnp.asarray(pad, x.dtype), [(D, R * L - n, 0)])
+        t = jnp.arange(R * L, dtype=self.idx)
+        base = self.base.astype(self.idx)
+        end = (self.base + self.counts).astype(self.idx)
+        out = jnp.full(R * L, pad, x.dtype)
+        lim = jnp.zeros(R * L, self.idx)
+        for s in range(S):
+            inside = t >= base[s]
+            sl = lax.dynamic_slice(xp, (D - self.d[s],), (R * L,))
+            out = jnp.where(inside, sl, out)
+            lim = jnp.where(inside, end[s], lim)
+        return jnp.where(t < lim, out, pad).reshape(R, L)
+
+    def from_rows(self, mat):
+        """The matrix's valid cells back in grouped layout:
+        ``out[p] = flat[p + d[seg_g[p]]]``."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        n = self.n
+        flat = mat.reshape(-1)
+        if self.S > _SLICED_SEGMENTS_MAX:
+            return flat[jnp.arange(n, dtype=self.d.dtype) + self.d[self.seg_g]]
+        # Slot p belongs to the last segment starting at or before it.
+        p = jnp.arange(n, dtype=self.idx)
+        starts = self.starts.astype(self.idx)
+        out = flat[:n]  # d[0] = 0
+        for s in range(1, self.S):
+            sl = lax.dynamic_slice(flat, (self.d[s],), (n,))
+            out = jnp.where(p >= starts[s], sl, out)
+        return out
+
+
+def _emit_index(order, seg_g, q, counts, starts, L: int):
+    """Each grouped slot's emission index (Alg. 3).
+
+    Arrivals past a segment's first L emit on arrival, in arrival order;
+    then every segment's last ``min(count, L)`` slots flush, segment-major.
+    Only the first L arrivals of each segment do not emit on arrival, so an
+    arrival's emission ordinal is its index less the number of those
+    arrivals up to it, and the arrival with in-segment rank ``q + L``
+    (grouped slot ``p + L``) is the one that emitted slot ``p``: a static
+    shift by L.
+    """
+    import jax.numpy as jnp
+
+    i64 = jnp.int64
+    n = int(order.shape[0])
+    if not n:  # a hop with no arrivals (an ingress group with no flow)
+        return jnp.zeros(0, i64)
+    n_emitted = jnp.maximum(counts - L, 0)
+    flush_mask = q >= n_emitted[seg_g]
+    flush_ord = n_emitted.sum() + jnp.cumsum(flush_mask).astype(i64) - 1
+    j = jnp.arange(L, dtype=i64)[None, :]
+    first = jnp.where(
+        j < counts[:, None],
+        order[jnp.clip(starts[:, None] + j, 0, n - 1)],
+        n,
+    )
+    # The search compares each arrival with all S*L first arrivals where
+    # that table is small, in 32 bits where n fits.
+    idx = _index_dtype(n + 1)
+    oi = order.astype(idx)
+    method = "compare_all" if first.size <= _COMPARE_ALL_MAX else "sort"
+    emit_ord_g = oi - jnp.searchsorted(
+        jnp.sort(first.reshape(-1).astype(idx)), oi, side="right",
+        method=method,
+    ).astype(idx)
+    shifted = jnp.concatenate(
+        [emit_ord_g[L:], jnp.zeros(min(L, n), idx)]
+    ).astype(i64)
+    return jnp.where(flush_mask, flush_ord, shifted)
 
 
 def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
@@ -160,7 +320,9 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
 
     ``vbits`` is the key domain's bit width (0 when packed sorts are
     infeasible — huge domains fall back to stable argsorts, byte-identical
-    but slower).
+    but slower).  No stage scatters: each traced hop counts
+    ``epoch_hop:scatter_free:int64`` in
+    :data:`repro.kernels.ops.LOWERING_COUNTS`.
 
     Each stage runs in a ``jax.named_scope`` of its name (``route``,
     ``rank``, ``block_sort``, ``emit``, ``ship``), which names its
@@ -169,9 +331,12 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
     import jax
     import jax.numpy as jnp
 
+    from ..kernels import ops
+
     i64 = jnp.int64
     n = int(vals.shape[0])
     packable = vbits > 0
+    ops.LOWERING_COUNTS["epoch_hop:scatter_free:int64"] += 1
     with jax.named_scope("route"):
         seg = jnp.searchsorted(bounds, vals, side="right").astype(i64)
     with jax.named_scope("rank"):
@@ -180,32 +345,17 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
         else:
             order = jnp.argsort(seg, stable=True)
             seg_g = seg[order]
-        counts = jnp.bincount(seg, length=S).astype(i64)
-        starts = jnp.concatenate(
-            [jnp.zeros(1, i64), jnp.cumsum(counts)[:-1]]
-        )
+        # seg_g is sorted: each segment's edges are searches into it.
+        edges = jnp.searchsorted(
+            seg_g, jnp.arange(S + 1, dtype=i64), side="left"
+        ).astype(i64)
+        starts, counts = edges[:-1], edges[1:] - edges[:-1]
         q = jnp.arange(n, dtype=i64) - starts[seg_g]  # in-segment position
-        ranks = jnp.zeros(n, i64).at[order].set(q)
         grouped = vals[order]
 
     # -- block sort: rows of one padded (R, L) matrix -------------------
     with jax.named_scope("block_sort"):
-        nblk = -(-counts // L)
-        blk_base = jnp.concatenate(
-            [jnp.zeros(1, i64), jnp.cumsum(nblk)[:-1]]
-        )
-        R = n // L + S  # static row budget; used rows are 0..sum(nblk)-1
-        row_of = blk_base[seg_g] + q // L
-        col_of = q % L
-        row_len = jnp.zeros(R, i64).at[row_of].add(1)
-        # Rows are (segment, block)-ordered and contiguous in grouped layout.
-        row_start = jnp.concatenate(
-            [jnp.zeros(1, i64), jnp.cumsum(row_len)[:-1]]
-        )
-        valid = jnp.arange(L, dtype=i64)[None, :] < row_len[:, None]
-        tgt = jnp.where(
-            valid, row_start[:, None] + jnp.arange(L, dtype=i64)[None, :], n
-        ).reshape(-1)
+        blocks = _BlockRows(seg_g, counts, starts, S, L)
         cbits = max(1, (L - 1).bit_length())
         if rows is not None and packable and vbits + cbits <= 63:
             # Record mode, packed: each cell carries ``(value << cbits) |
@@ -216,70 +366,42 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
             # own column in the low bits: they sort after every real key
             # (ties with a real max-valued key break toward the real key's
             # smaller column — the same stable tie-break as the fused
-            # engine's provenance lexsort) and land on dropped (``tgt ==
-            # n``) output slots.
+            # engine's provenance lexsort) and never return to a slot.
             pad_val = (1 << vbits) - 1
             cmask = (1 << cbits) - 1
-            cols = jnp.arange(L, dtype=i64)[None, :]
-            pk = jnp.broadcast_to((pad_val << cbits) | cols, (R, L))
-            pk = pk.at[row_of, col_of].set((grouped << cbits) | col_of)
+            pk = blocks.to_rows(grouped << cbits, pad_val << cbits)
+            pk = pk | blocks.cols
             spk = jnp.sort(pk, axis=1)
-            sorted_vals = spk >> cbits
             src = jnp.clip(
-                row_start[:, None] + (spk & cmask), 0, max(n - 1, 0)
+                blocks.row_start[:, None] + (spk & cmask), 0, max(n - 1, 0)
             )
-            stream = _scatter_rows(tgt, sorted_vals, n)
-            src_slot = _scatter_rows(tgt, src, n)
-            stream_rows = rows[order][src_slot]
+            stream = blocks.from_rows(spk >> cbits)
+            stream_rows = rows[order][blocks.from_rows(src)]
         elif rows is not None:
             # Record mode, wide keys: a stable row argsort keeps the
             # within-block arrival order on ties — the same tie-break as
             # the fused engine's provenance lexsort — so payload rows
             # follow their keys exactly.
-            pad = jnp.iinfo(i64).max
-            mat = jnp.full((R, L), pad, i64).at[row_of, col_of].set(grouped)
-            pmat = jnp.full((R, L), n, i64).at[row_of, col_of].set(
-                jnp.arange(n, dtype=i64)
-            )
+            mat = blocks.to_rows(grouped, jnp.iinfo(i64).max)
             perm = jnp.argsort(mat, axis=1, stable=True)
             sorted_vals = jnp.take_along_axis(mat, perm, axis=1)
-            sorted_pos = jnp.take_along_axis(pmat, perm, axis=1)
-            stream = _scatter_rows(tgt, sorted_vals, n)
-            src_slot = _scatter_rows(tgt, sorted_pos, n)
-            stream_rows = rows[order][src_slot]
+            sorted_pos = jnp.take_along_axis(blocks.slots(), perm, axis=1)
+            stream = blocks.from_rows(sorted_vals)
+            stream_rows = rows[order][blocks.from_rows(sorted_pos)]
         elif use_kernel:
-            from ..kernels import ops  # deferred: only when the backend asks
-
             pad32 = jnp.iinfo(jnp.int32).max
-            mat32 = jnp.full((R, L), pad32, jnp.int32).at[row_of, col_of].set(
-                grouped.astype(jnp.int32)
-            )
+            mat32 = blocks.to_rows(grouped.astype(jnp.int32), pad32)
             sorted32 = ops.sort_rows_padded(mat32, interpret=interpret)
-            stream = _scatter_rows(tgt, sorted32.astype(i64), n)
+            stream = blocks.from_rows(sorted32).astype(i64)
             stream_rows = None
         else:
-            pad = jnp.iinfo(i64).max
-            mat = jnp.full((R, L), pad, i64).at[row_of, col_of].set(grouped)
-            sorted_vals = jnp.sort(mat, axis=1)
-            stream = _scatter_rows(tgt, sorted_vals, n)
+            mat = blocks.to_rows(grouped, jnp.iinfo(i64).max)
+            stream = blocks.from_rows(jnp.sort(mat, axis=1))
             stream_rows = None
 
     # -- emission order: slot → emission index --------------------------
     with jax.named_scope("emit"):
-        emit_mask = ranks >= L
-        emit_slot = starts[seg] + ranks - L
-        emit_ord = jnp.cumsum(emit_mask).astype(i64) - 1
-        n_emitted = jnp.maximum(counts - L, 0)
-        n_emit_total = n_emitted.sum()
-        flush_mask = q >= n_emitted[seg_g]
-        flush_ord = n_emit_total + jnp.cumsum(flush_mask).astype(i64) - 1
-        eidx = (
-            jnp.zeros(n + 1, i64)
-            .at[jnp.where(emit_mask, emit_slot, n)]
-            .set(jnp.where(emit_mask, emit_ord, 0))
-            .at[jnp.where(flush_mask, jnp.arange(n, dtype=i64), n)]
-            .set(jnp.where(flush_mask, flush_ord, 0))
-        )[:n]
+        eidx = _emit_index(order, seg_g, q, counts, starts, L)
 
     # -- wire order: packets ship at their last key's emission ----------
     with jax.named_scope("ship"):

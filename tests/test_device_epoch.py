@@ -36,6 +36,8 @@ from repro.net import (
     tree_graph,
 )
 from repro.net.device_epoch import (
+    _COMPARE_ALL_MAX,
+    _SLICED_SEGMENTS_MAX,
     TRANSFER_COUNTS,
     device_self_check,
     reset_transfer_counts,
@@ -218,6 +220,143 @@ def test_epoch_program_names_its_stages():
         for stage in ("route", "rank", "block_sort", "emit", "ship"):
             assert f'"jit(epoch_fn)/hop_{hop}/{stage}/' in text, (hop, stage)
     assert '"jit(epoch_fn)/hop_spine/uplink_merge/' in text
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize(
+    "topo,carry_rows",
+    [("single", False), ("leaf_spine", False), ("single", True)],
+    ids=["keys_single", "keys_leaf_spine", "records_packed"],
+)
+def test_epoch_program_has_no_scatter(topo, carry_rows):
+    """Ranks, counts, the block layout and the emission order lower without
+    a scatter (the Pallas kernel is off: interpret-mode Pallas is plain HLO
+    with scatters of its own)."""
+    import jax
+
+    from repro.core.partition import set_ranges
+    from repro.kernels.ops import x64_scope
+    from repro.net.device_epoch import _epoch_program
+    from repro.net.topology import HopGraph, HopNode
+
+    max_value = scenario_max_value("drifting")
+    spec = HopSpec(
+        SEGS, LENGTH, max_value, set_ranges(max_value, SEGS), payload_size=32
+    )
+    if topo == "single":
+        graph, group_ns = HopGraph((HopNode("switch"),), num_groups=1), (1000,)
+    else:
+        graph, group_ns = leaf_spine_graph(2), (500, 500)
+    fn = _epoch_program(
+        graph, spec, spec.ranges, group_ns, carry_rows, False, None, False
+    )
+    args = tuple(jax.ShapeDtypeStruct((g,), np.int64) for g in group_ns)
+    with x64_scope():
+        text = fn.lower(args, args if carry_rows else ()).as_text()
+    assert "stablehlo.sort" in text
+    assert "stablehlo.scatter" not in text
+
+
+def _segment_batch(counts, S, max_value, lo=0, seed=0):
+    """Arrivals holding exactly ``counts[s]`` keys of segment ``s`` of the
+    equal-width ranges over ``[lo, lo + max_value]``, in a shuffled order."""
+    from repro.core.partition import set_ranges
+
+    ranges = set_ranges(max_value, S) + lo
+    rng = np.random.default_rng(seed)
+    vals = np.concatenate([
+        rng.integers(a, b, c) for (a, b), c in zip(ranges, counts)
+    ]).astype(np.int64)
+    rng.shuffle(vals)
+    flows = split_flows(vals, 2, 32)
+    return interleave_batch(flows, "round_robin", seed=seed), ranges
+
+
+#: More segments than the sliced block layout takes, a fifth of them empty.
+_MANY_SEGS = _SLICED_SEGMENTS_MAX + 8
+_SKEWED_MANY = [(s * 7) % 13 if s % 5 else 0 for s in range(_MANY_SEGS)]
+#: A block length whose 16 segments' first arrivals outnumber the table the
+#: emission search compares with.
+_SORT_SEARCH_L = 2 * _COMPARE_ALL_MAX // 16
+
+_HOP_EDGES = {
+    # name: (S, L, per-segment counts, domain (offset, max value), payload
+    # rows carried)
+    "one_segment": (8, 16, [0, 0, 0, 300, 0, 0, 0, 0], (0, 799), False),
+    "empty_segments": (8, 16, [50, 0, 0, 37, 0, 120, 0, 9], (0, 799), False),
+    "all_below_L": (8, 16, [3, 15, 1, 7, 0, 12, 15, 5], (0, 799), False),
+    "exact_L_and_kL": (8, 16, [16, 32, 48, 16, 64, 160, 16, 32], (0, 799),
+                       False),
+    "n_below_L": (8, 64, [1, 2, 0, 3, 0, 0, 5, 1], (0, 799), False),
+    "gathered_layout": (_MANY_SEGS, 4, _SKEWED_MANY, (0, 799), False),
+    "records_exact_L_and_kL": (8, 16, [16, 32, 48, 16, 64, 160, 16, 32],
+                               (0, 799), True),
+    "records_gathered_layout": (_MANY_SEGS, 4, _SKEWED_MANY, (0, 799), True),
+    "search_by_sort": (16, _SORT_SEARCH_L, [3000, 0, 2048, 100, 0, 4096, 7,
+                                            0, 0, 0, 1, 2049, 0, 0, 0, 0],
+                       (0, 1 << 16), False),
+    "records_wide_keys": (8, 16, [50, 0, 0, 37, 16, 120, 0, 9],
+                          (0, (1 << 61) - 1), True),
+    "negative_domain": (8, 16, [50, 0, 0, 37, 16, 120, 0, 9], (-400, 799),
+                        False),
+}
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize("case", list(_HOP_EDGES))
+def test_device_hop_edge_layouts(case, monkeypatch):
+    """The scatter-free forms where the shift by L and the closed-form row
+    lengths could slip — one crowded segment, empty segments, segments
+    under L, exactly L and kL keys, fewer keys than L, more segments than
+    the sliced layout takes, more first arrivals than the search compares,
+    packed and wide records, a negative domain (stable argsorts in place of
+    packed sorts) — each byte-identical to ``fused``."""
+    from repro.kernels import ops
+    from repro.net import device_epoch
+
+    S, L, counts, (lo, max_value), carry_rows = _HOP_EDGES[case]
+    batch, ranges = _segment_batch(counts, S, max_value, lo=lo)
+    if carry_rows:
+        batch = batch.with_row_index(np.arange(len(batch), dtype=np.int64))
+    spec = HopSpec(S, L, max_value, ranges, payload_size=8)
+    monkeypatch.setattr(device_epoch, "_PROGRAM_CACHE", {})
+    monkeypatch.setattr(ops, "LOWERING_COUNTS", type(ops.LOWERING_COUNTS)())
+    of, sf = run_hop(batch, spec, "sw", engine="fused")
+    od, sd = run_hop(batch, spec, "sw", engine="device")
+    assert dict(ops.LOWERING_COUNTS) == {"epoch_hop:scatter_free:int64": 1}
+    _assert_batches_equal(od, of, case)
+    if carry_rows:
+        np.testing.assert_array_equal(od.row_index, of.row_index)
+    np.testing.assert_array_equal(sd.ship_emission, sf.ship_emission)
+    np.testing.assert_array_equal(sd.segment_loads, counts)
+    assert sd == sf
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize(
+    "topo,topo_kw,num_flows,segments",
+    [
+        ("leaf_spine", {"num_leaves": 2}, 1, SEGS),
+        ("leaf_spine", {"num_leaves": 2}, 1, _MANY_SEGS),
+        ("tree", {"branching": 2, "height": 3}, 3, SEGS),
+    ],
+    ids=["leaf_spine_one_flow", "leaf_spine_one_flow_gathered",
+         "tree_fewer_flows_than_leaves"],
+)
+def test_device_epoch_with_an_idle_leaf(topo, topo_kw, num_flows, segments):
+    """An ingress group with no flow gives its leaf a hop with no arrivals;
+    the epoch still runs, byte-identical to ``fused``."""
+    vals = SCENARIOS["drifting"](N, seed=5)
+    kw = _common("drifting", num_flows=num_flows, num_segments=segments,
+                 verify=True)
+    rd = run_pipeline(vals, topology=topo, engine="device", **kw, **topo_kw)
+    rf = run_pipeline(vals, topology=topo, engine="fused", **kw, **topo_kw)
+    np.testing.assert_array_equal(rd.output, rf.output)
+    assert rd.passes == rf.passes
+    _assert_batches_equal(rd.delivered, rf.delivered)
+    assert [s.arrivals for s in rd.hop_stats].count(0) >= 1
+    for sd, sf in zip(rd.hop_stats, rf.hop_stats):
+        assert sd == sf
 
 
 # -- payload records ----------------------------------------------------
