@@ -1,13 +1,14 @@
 """Readings that set the limits of ``correct``: the program's and the
-control's, on many seeds, at a cell's own size.
+controls', on many seeds, at a cell's own size.
 
     python3 bench/control.py --workload keys_random --seeds 1 2 3 [--program]
 
 For each seed the cell's relation is drawn and the plain reference built.
-The control, ``int8_key``, stands in the program's place
-(``bench/reference.py``): keys held at 8 bits of precision, for the route
-and the order alike, the step to a narrower key that would tempt a later
-change.
+Each of the relation's controls (``CONTROLS`` in
+``bench/relations/<relation>.py``) stands in the program's place: the
+reference with one guarantee broken, such as ``int_keys``'s ``int8_key``,
+keys held at 8 bits of precision for the route and the order alike, the
+step to a narrower key that would tempt a later change.
 
 With ``--program`` the program itself runs one job per seed, through the
 same call as the benchmark's window, in this one process (one warm-up job
@@ -25,26 +26,21 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-import reference  # noqa: E402
 import run  # noqa: E402
-
-CONTROLS = {"int8_key": dict(key_bits=8)}
 
 
 def readings(cell: run.Cell, seeds, program: bool) -> list[dict]:
+    relation = run.load_relation(cell)
     rows = []
     if program:
-        run.Workload(cell, seeds[0]).job()  # warm-up: compile or load
+        relation.Workload(cell, seeds[0]).job()  # warm-up: compile or load
     for seed in seeds:
-        work = run.Workload(cell, seed)
+        work = relation.Workload(cell, seed)
         answers = {}
         if program:
             _, answers["program"] = work.job()
-        for name, kw in CONTROLS.items():
-            answers[name] = reference.control_answer(
-                work.keys, work.max_value, int(work.kwargs["num_segments"]),
-                **kw,
-            )
+        for name, make in relation.CONTROLS.items():
+            answers[name] = make(work)
         ref = work.reference()
         for name, answer in answers.items():
             row = {"seed": seed, "answer": name, **ref.compare(answer)}

@@ -4,11 +4,15 @@
 
 A cell names a configuration (``bench/configs/<config>.json``: the job's
 size, the fabric, the guarantees) and a traffic mix
-(``bench/traffic/<traffic>.json``, drawn by ``bench/traffic.py``).  The
-cell's own file, ``bench/workloads/<cell>.json``, holds the limit of each
-number that decides ``correct``.  A per-layer metric is a reader,
-``bench/metrics/<name>.py``.  This file names no cell, configuration or
-metric: a new one is new files and ``BENCHMARK.json`` entries.
+(``bench/traffic/<traffic>.json``).  The configuration's ``relation``
+names what a job is and what it answers, ``int_keys`` where it names none:
+the module ``bench/relations/<relation>.py`` draws the relation from the
+traffic's parameters, makes the one timed call and builds the plain
+reference.  The cell's own file, ``bench/workloads/<cell>.json``, holds
+the limit of each number that decides ``correct``.  A per-layer metric is
+a reader, ``bench/metrics/<name>.py``.  This file names no cell,
+configuration, relation or metric beyond that default: a new one is new
+files and ``BENCHMARK.json`` entries.
 
 Set-up: draw the relation from ``--seed``, build its reference, then run
 one warm-up job, which compiles, or loads from JAX's persistent cache,
@@ -16,17 +20,18 @@ every program the window runs.  The window, with ``--trace 0``, is a
 closed loop with one client: jobs run one after another, each on a fresh
 copy of the same relation made outside the clock, and start while their
 summed time is below ``--seconds``.  A job is one
-``repro.net.run_pipeline`` call, from the keys on the host to the sorted
-keys on the host.  With ``--trace 1`` two jobs run and the JAX profiler
-records the second, and the per-layer metrics are read from its trace.
+``repro.net.run_pipeline`` call, from the relation on the host to the
+sorted relation on the host.  With ``--trace 1`` two jobs run and the JAX
+profiler records the second, and the per-layer metrics are read from its
+trace.
 
-The reference's build (``bench/reference.py``) is left out of
-``setup_s``.  Each job's answer is compared with it right after the job,
-outside the clock, so that the process holds one answer at a time; only
-the counts are kept.  Once the window has closed, the device's peak memory
-is read.  The last line of standard output is the result as JSON.  Without
-a TPU, with fewer chips than the cell asks for, or when any sort or merge
-ran in Pallas interpret mode, the run exits non-zero and prints no result.
+The reference's build is left out of ``setup_s``.  Each job's answer is
+compared with it right after the job, outside the clock, so that the
+process holds one answer at a time; only the counts are kept.  Once the
+window has closed, the device's peak memory is read.  The last line of
+standard output is the result as JSON.  Without a TPU, with fewer chips
+than the cell asks for, or when any sort or merge ran in Pallas interpret
+mode, the run exits non-zero and prints no result.
 """
 
 from __future__ import annotations
@@ -37,7 +42,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import collections  # noqa: E402
-import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -50,7 +54,6 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
-import reference  # noqa: E402
 import traffic  # noqa: E402
 import tracefile  # noqa: E402
 
@@ -139,55 +142,29 @@ def require_kernels(lowering: dict) -> None:
         raise SystemExit(f"Pallas interpret mode ran: {slow}")
 
 
-class Workload:
-    """The cell's relation and the one call that sorts it."""
-
-    def __init__(self, cell: Cell, seed: int):
-        cfg = cell.config
-        self.n = int(cfg["keys_per_job"])
-        self.keys = traffic.draw_keys(cell.traffic, self.n, seed)
-        self.max_value = traffic.max_value(cell.traffic)
-        self.kwargs = dict(
-            cfg["pipeline"], max_value=self.max_value, seed=seed % (1 << 32)
-        )
-
-    def job(self, span=contextlib.nullcontext):
-        """Run one job inside ``span()``; return its seconds and answer."""
-        import repro.net
-
-        keys = self.keys.copy()
-        with span():
-            t0 = time.perf_counter()
-            res = repro.net.run_pipeline(keys, **self.kwargs)
-            seconds = time.perf_counter() - t0
-        answer = reference.Answer(
-            output=res.output,
-            wire_keys=res.delivered.values,
-            wire_segments=res.delivered.segment_id,
-        )
-        return seconds, answer
-
-    def reference(self) -> reference.Reference:
-        if self.kwargs.get("range_mode") != "static":
-            raise SystemExit(
-                "the delivery check knows Alg. 2's static ranges only"
-            )
-        return reference.Reference.build(
-            self.keys, self.max_value, int(self.kwargs["num_segments"])
-        )
-
-
 def _program_files() -> frozenset:
     """File names of the program's modules, which label idle gaps."""
     return frozenset(p.name for p in (ROOT / "src").rglob("*.py"))
 
 
-def load_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def _load_module(kind: str, name: str):
+    """The module ``bench/<kind>/<name>.py``, loaded by its path."""
+    path = BENCH / kind / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # dataclasses look their module up here
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str):
+    return _load_module("metrics", name).read
+
+
+def load_relation(cell: Cell):
+    """The cell's relation module, ``bench/relations/<kind>.py``: the
+    configuration's ``relation``, ``int_keys`` where it names none."""
+    return _load_module("relations", cell.config.get("relation", "int_keys"))
 
 
 def _device_report() -> dict:
@@ -211,7 +188,7 @@ class Judge:
     only the counts: the jobs attempted and failed and, per number, the
     worst value over the jobs."""
 
-    def __init__(self, cell: Cell, ref: reference.Reference):
+    def __init__(self, cell: Cell, ref):
         self.cell, self.ref = cell, ref
         self.attempted = self.failed = 0
         self.worst: dict[str, int] = {}
@@ -271,7 +248,7 @@ def _run(cell, seed, seconds, trace, check_chip, compiles) -> dict:
     from repro.kernels import ops
     from repro.net import device_epoch
 
-    work = Workload(cell, seed)
+    work = load_relation(cell).Workload(cell, seed)
     t0 = time.perf_counter()
     judge = Judge(cell, work.reference())
     reference_s = time.perf_counter() - t0
