@@ -232,7 +232,9 @@ def pool_concat(
     (epoched re-partitioning: server ranges overlap) k-way merges the
     sorted server streams — inherently sequential, always on the host.
     """
-    outs = [np.asarray(o, dtype=np.int64) for o in outs]
+    # Wide records (rows of int32 sort words) keep their dtype.
+    outs = [o if np.ndim(o) == 2 else np.asarray(o, dtype=np.int64)
+            for o in outs]
     if not outs:
         return np.zeros(0, dtype=np.int64)
     if len(outs) == 1:

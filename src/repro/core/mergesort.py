@@ -26,6 +26,7 @@ import numpy as np
 from repro.obs.trace import NULL_TRACER
 from repro.transfers import fetch, to_device
 
+from .partition import _TOP_BIT
 from .runs import run_starts
 
 
@@ -50,7 +51,13 @@ def merge_two(a: np.ndarray, b: np.ndarray) -> np.ndarray:
 
 
 def merge_runs(runs: list[np.ndarray]) -> np.ndarray:
-    """Merge sorted runs into one via a tournament of two-way merges."""
+    """Merge sorted runs into one via a tournament of two-way merges.
+
+    Runs of wide keys (2-D: one row of int32 sort words per key) merge as
+    byte strings whose memcmp order is the rows' lexicographic order."""
+    if runs and np.ndim(runs[0]) == 2:
+        width = runs[0].shape[1]
+        return _from_bytes(merge_runs([_as_bytes(r) for r in runs]), width)
     while len(runs) > 1:
         nxt = []
         for i in range(0, len(runs) - 1, 2):
@@ -59,6 +66,21 @@ def merge_runs(runs: list[np.ndarray]) -> np.ndarray:
             nxt.append(runs[-1])
         runs = nxt
     return runs[0]
+
+
+def _as_bytes(words: np.ndarray) -> np.ndarray:
+    """Rows of int32 sort words as fixed-width byte strings: big-endian
+    unsigned words, so memcmp order is the rows' lexicographic order (numpy
+    compares equal-width byte strings bytewise)."""
+    be = (np.asarray(words, dtype=np.int32) ^ _TOP_BIT).view(np.uint32)
+    be = np.ascontiguousarray(be.astype(">u4"))
+    return be.view(f"S{4 * be.shape[1]}").ravel()
+
+
+def _from_bytes(strings: np.ndarray, width: int) -> np.ndarray:
+    """Inverse of :func:`_as_bytes`."""
+    be = np.ascontiguousarray(strings).view(">u4").reshape(-1, width)
+    return be.astype(np.uint32).view(np.int32) ^ _TOP_BIT
 
 
 def _merge_set(arr: np.ndarray, starts: np.ndarray, ends: np.ndarray) -> np.ndarray:
@@ -97,7 +119,11 @@ def _device_dtype(lo: int, hi: int) -> np.dtype | None:
     key at the sentinel would be indistinguishable from padding, so it drops
     to the numpy ladder rather than lean on multiset arguments.  Keys beyond
     int32 — the packed key+payload-row records of the device dataplane —
-    merge as int64, which the tournament runs under an x64 scope.
+    merge as int64, which the tournament runs under an x64 scope.  Wide
+    records (keys wider than 64 bits) never come here: their rows of int32
+    sort words, the input row last, merge as stacked int32 words in
+    :func:`merge_runs_flat`, where an all-max pad tuple sorts above every
+    real row.
     """
     if 0 <= lo and hi < np.iinfo(np.uint16).max:
         return np.dtype(np.uint16)
@@ -144,6 +170,10 @@ def merge_runs_flat(
     keep = lengths > 0
     if not keep.all():
         starts, lengths = starts[keep], lengths[keep]
+    if np.ndim(buf) == 2:
+        return _merge_runs_flat_wide(
+            buf, starts, lengths, min_device_keys, interpret, tr, tid
+        )
     R = int(starts.size)
     if R == 0:
         return np.zeros(0, dtype=np.int64)
@@ -171,43 +201,100 @@ def merge_runs_flat(
 
         _merge_scope = contextlib.nullcontext
     pad = dtype.type(np.iinfo(dtype).max)
-    # Vectorized next-pow2 (float64 log2 is exact for any realistic length).
-    buckets = (2 ** np.ceil(np.log2(lengths))).astype(np.int64)
     winners: list[np.ndarray] = []
-    for B in np.unique(buckets):
-        B = int(B)
-        members = np.nonzero(buckets == B)[0]
-        # One tournament holds at most the kernel's VMEM cap of keys; a
-        # larger bucket splits, and its parts meet in the host merge below.
-        per_call = max(1, bitonic.TOURNAMENT_MAX_ELEMS // B)
-        for first in range(0, members.size, per_call):
-            part = members[first : first + per_call]
-            P = int(part.size)
-            if P == 1:
-                i = int(part[0])
-                winners.append(buf[starts[i] : starts[i] + lengths[i]])
-                continue
-            rows = max(2, _next_pow2(P))
-            with tr.span(
-                f"tournament:b{B}", cat="server", tid=tid, runs=P,
-                bytes=2 * rows * B * dtype.itemsize,
-            ):
-                sl = lengths[part]
-                mat = np.full((rows, B), pad, dtype)
-                mat.flat[_ragged_gather(np.arange(P) * B, sl)] = buf[
-                    _ragged_gather(starts[part], sl)
-                ]
-                with _merge_scope():
-                    merged = fetch(
-                        ops.merge_tournament(
-                            to_device(mat), interpret=interpret
-                        )
+    for B, part in _bucket_parts(lengths, bitonic.TOURNAMENT_MAX_ELEMS):
+        P = int(part.size)
+        if P == 1:
+            i = int(part[0])
+            winners.append(buf[starts[i] : starts[i] + lengths[i]])
+            continue
+        rows = max(2, _next_pow2(P))
+        with tr.span(
+            f"tournament:b{B}", cat="server", tid=tid, runs=P,
+            bytes=2 * rows * B * dtype.itemsize,
+        ):
+            sl = lengths[part]
+            mat = np.full((rows, B), pad, dtype)
+            mat.flat[_ragged_gather(np.arange(P) * B, sl)] = buf[
+                _ragged_gather(starts[part], sl)
+            ]
+            with _merge_scope():
+                merged = fetch(
+                    ops.merge_tournament(
+                        to_device(mat), interpret=interpret
                     )
-                winners.append(merged[: int(sl.sum())])
+                )
+            winners.append(merged[: int(sl.sum())])
     if len(winners) == 1:
         return winners[0].astype(np.int64)
     with tr.span("winners", cat="server", tid=tid, runs=len(winners)):
         return np.asarray(merge_runs(winners), dtype=np.int64)
+
+
+def _bucket_parts(lengths: np.ndarray, cap: int):
+    """``(B, members)`` of each tournament call: runs bucketed by
+    power-of-two length ``B``, a bucket split so that a call holds at most
+    ``cap`` keys (its parts meet in the host merge of the winners)."""
+    # Vectorized next-pow2 (float64 log2 is exact for any realistic length).
+    buckets = (2 ** np.ceil(np.log2(lengths))).astype(np.int64)
+    for B in np.unique(buckets):
+        B = int(B)
+        members = np.nonzero(buckets == B)[0]
+        per_call = max(1, cap // B)
+        for first in range(0, members.size, per_call):
+            yield B, members[first : first + per_call]
+
+
+def _merge_runs_flat_wide(buf, starts, lengths, min_device_keys, interpret,
+                          tr, tid) -> np.ndarray:
+    """:func:`merge_runs_flat` of wide records: ``buf`` holds one int32 row
+    of ``K`` sort words per key (the input row last, so rows are distinct).
+    Each tournament merges the ``K`` words stacked ``(K, P, B)`` as tuples
+    (:func:`repro.kernels.ops.merge_tournament`, in XLA), pads all-max, and
+    holds at most :data:`~repro.kernels.bitonic.TOURNAMENT_MAX_ELEMS` words
+    as the one-word kernel holds keys, which keeps the rows' padding to a
+    power of two small; returns ``(n, K)`` int32."""
+    K = buf.shape[1]
+    R = int(starts.size)
+    if R == 0:
+        return np.zeros((0, K), dtype=np.int32)
+    if R == 1:
+        s = int(starts[0])
+        return buf[s : s + int(lengths[0])].copy()
+    if int(lengths.sum()) < min_device_keys:
+        return merge_runs([buf[s : s + l] for s, l in zip(starts, lengths)])
+    from ..kernels import bitonic, ops  # deferred: jax import is heavy
+
+    pad = np.iinfo(np.int32).max
+    winners: list[np.ndarray] = []
+    cap = 1 << ((bitonic.TOURNAMENT_MAX_ELEMS // K).bit_length() - 1)
+    for B, part in _bucket_parts(lengths, cap):
+        P = int(part.size)
+        if P == 1:
+            i = int(part[0])
+            winners.append(buf[starts[i] : starts[i] + lengths[i]])
+            continue
+        rows = max(2, _next_pow2(P))
+        with tr.span(
+            f"tournament:b{B}", cat="server", tid=tid, runs=P,
+            bytes=2 * K * rows * B * 4,
+        ):
+            sl = lengths[part]
+            m = int(sl.sum())
+            mat = np.full((K, rows * B), pad, np.int32)
+            mat[:, _ragged_gather(np.arange(P) * B, sl)] = np.take(
+                buf, _ragged_gather(starts[part], sl), axis=0
+            ).T
+            merged = fetch(
+                ops.merge_tournament(
+                    to_device(mat.reshape(K, rows, B)), interpret=interpret
+                )
+            )
+            winners.append(merged[:, :m].T)
+    if len(winners) == 1:
+        return np.ascontiguousarray(winners[0])
+    with tr.span("winners", cat="server", tid=tid, runs=len(winners)):
+        return merge_runs(winners)
 
 
 def merge_runs_batched(

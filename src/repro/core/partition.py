@@ -37,6 +37,61 @@ def set_ranges(max_value: int, num_segments: int) -> np.ndarray:
     return np.stack([lo, hi], axis=1)
 
 
+#: Key widths, in bytes, that ride as sort words (:func:`key_words`).
+WIDE_KEY_BYTES = range(9, 17)
+
+#: XOR with this flips a 32-bit word's top bit: unsigned order as int32's.
+_TOP_BIT = np.int32(-(1 << 31))
+
+
+def key_words(keys: np.ndarray) -> np.ndarray:
+    """``(n, B)`` uint8 keys as ``(n, ceil(B/4))`` int32 *sort words*.
+
+    Word ``i`` is key bytes ``4i .. 4i+3`` read as a big-endian uint32
+    (the last word zero-filled), its top bit flipped, so signed int32
+    order is the bytes' unsigned order and the words compared
+    lexicographically keep the keys' memcmp order.
+    """
+    keys = np.asarray(keys, dtype=np.uint8)
+    n, b = keys.shape
+    w = -(-b // 4)
+    padded = np.zeros((n, 4 * w), dtype=np.uint8)
+    padded[:, :b] = keys
+    return padded.view(">u4").astype(np.uint32).view(np.int32) ^ _TOP_BIT
+
+
+def words_to_keys(words: np.ndarray, key_bytes: int) -> np.ndarray:
+    """Inverse of :func:`key_words`: sort words back to ``(n, key_bytes)``
+    uint8 keys."""
+    words = np.asarray(words, dtype=np.int32)
+    be = (words ^ _TOP_BIT).view(np.uint32).astype(">u4")
+    return np.ascontiguousarray(
+        np.ascontiguousarray(be).view(np.uint8)[:, :key_bytes]
+    )
+
+
+def set_ranges_wide(key_bytes: int, num_segments: int) -> np.ndarray:
+    """Alg. 2's equal-width table over the whole domain ``[0, 256**B)`` of
+    ``B``-byte keys, as the ``(S, W)`` sort words of each range's lower
+    bound (row 0 is all zero bytes).
+
+    The bounds are exact Python ints, ``q, r = divmod(256**B, S)`` with
+    the first ``r`` ranges one wider, as :func:`set_ranges` splits.  A key's
+    segment is the number of rows 1..S-1 at or below it, compared
+    lexicographically.
+    """
+    if key_bytes not in WIDE_KEY_BYTES:
+        raise ValueError(f"wide keys are 9 to 16 bytes, got {key_bytes}")
+    if num_segments <= 0:
+        raise ValueError("num_segments must be positive")
+    q, r = divmod(256**key_bytes, num_segments)
+    lows = [s * q + min(s, r) for s in range(num_segments)]
+    raw = np.array(
+        [list(v.to_bytes(key_bytes, "big")) for v in lows], dtype=np.uint8
+    )
+    return key_words(raw)
+
+
 def segment_of(values: np.ndarray, ranges: np.ndarray) -> np.ndarray:
     """Vectorized SwitchInsert routing: which segment owns each value.
 

@@ -12,12 +12,33 @@ import dataclasses
 import numpy as np
 
 
+def descents(a: np.ndarray) -> np.ndarray:
+    """``a[1:] < a[:-1]``: where each next key falls below the one before
+    it.  A 2-D ``a`` holds one key per row, as word tuples compared
+    lexicographically (column 0 most significant)."""
+    a = np.asarray(a)
+    if a.ndim == 1:
+        return a[1:] < a[:-1]
+    nxt, prev = a[1:], a[:-1]
+    below = nxt[:, -1] < prev[:, -1]
+    for c in range(a.shape[1] - 2, -1, -1):
+        below = (nxt[:, c] < prev[:, c]) | ((nxt[:, c] == prev[:, c]) & below)
+    return below
+
+
+def _falls_below(key, prev) -> bool:
+    """Whether one key (a scalar, or a row of words) is below ``prev``."""
+    if np.ndim(key) == 0:
+        return int(key) < int(prev)
+    return bool(descents(np.stack([prev, key]))[0])
+
+
 def run_starts(a: np.ndarray) -> np.ndarray:
     """Indices where a new run starts (always includes 0 for non-empty a)."""
     a = np.asarray(a)
     if a.size == 0:
         return np.zeros(0, dtype=np.int64)
-    breaks = np.nonzero(a[1:] < a[:-1])[0] + 1
+    breaks = np.nonzero(descents(a))[0] + 1
     return np.concatenate([[0], breaks]).astype(np.int64)
 
 
@@ -67,7 +88,10 @@ class RunArena:
 
     Buffers grow by doubling; both the keys buffer and the offsets table are
     int64 end to end (the index math must survive >2^31 keys — pinned by the
-    regression tests in ``tests/test_run_arena.py``).
+    regression tests in ``tests/test_run_arena.py``).  Wide keys (2-D
+    payloads: one int32 row of sort words per key, the device epoch's words
+    and input row) make the first feed turn the buffer into such rows; runs
+    then break where the rows descend lexicographically.
     """
 
     def __init__(self, capacity: int = 1024) -> None:
@@ -85,28 +109,43 @@ class RunArena:
         return self._num_runs
 
     @property
-    def tail(self) -> int | None:
-        """Last key of the open run (None while the arena is empty)."""
-        return int(self._buf[self._n - 1]) if self._n else None
+    def tail(self):
+        """Last key of the open run (None while the arena is empty): an int,
+        or a row of words."""
+        if not self._n:
+            return None
+        last = self._buf[self._n - 1]
+        return int(last) if last.ndim == 0 else last.copy()
 
     def _grow(self, arr: np.ndarray, need: int) -> np.ndarray:
-        cap = arr.size
+        cap = len(arr)
         if need <= cap:
             return arr
         while cap < need:
             cap *= 2
-        out = np.empty(cap, dtype=arr.dtype)
-        out[: arr.size] = arr
+        out = np.empty((cap,) + arr.shape[1:], dtype=arr.dtype)
+        out[: len(arr)] = arr
         return out
+
+    def _opens_new(self, arr: np.ndarray) -> bool:
+        """Whether the payload ``arr`` opens a new run (its first key falls
+        below the open run's last).  The first payload fixes the buffer's
+        row shape."""
+        if self._n == 0:
+            if arr.ndim == 2:
+                cap = max(len(self._buf), len(arr))
+                self._buf = np.empty((cap, arr.shape[1]), np.int32)
+            return True
+        return _falls_below(arr[0], self._buf[self._n - 1])
 
     def feed(self, arr: np.ndarray) -> None:
         """Append one in-order payload; extend or break runs columnarly."""
         arr = np.asarray(arr)
-        m = int(arr.size)
+        m = len(arr)
         if m == 0:
             return
-        breaks = np.nonzero(arr[1:] < arr[:-1])[0] + 1
-        opens_new = self._n == 0 or int(arr[0]) < int(self._buf[self._n - 1])
+        breaks = np.nonzero(descents(arr))[0] + 1
+        opens_new = self._opens_new(arr)
         new_starts = breaks + self._n
         if opens_new:
             new_starts = np.concatenate([[self._n], new_starts])
@@ -130,13 +169,13 @@ class RunArena:
         continues across the boundary when the first key does not descend.
         """
         arr = np.asarray(arr)
-        m = int(arr.size)
+        m = len(arr)
         if m == 0:
             return
         starts = np.asarray(starts, dtype=np.int64)
         if starts.size == 0 or int(starts[0]) != 0:
             raise ValueError("run starts must begin at payload position 0")
-        opens_new = self._n == 0 or int(arr[0]) < int(self._buf[self._n - 1])
+        opens_new = self._opens_new(arr)
         new_starts = starts + self._n
         if not opens_new:
             new_starts = new_starts[1:]

@@ -11,7 +11,12 @@ MergeMarathon emitted stream (see repro.core.marathon).
 
 The run-arena tournament has two forms: the Pallas kernel
 (:func:`tournament_tiles`) and its XLA form (:func:`tournament_merge_array`),
-used for 64-bit keys and off-TPU.  The Pallas kernels never reshape the
+used for 64-bit keys and off-TPU.  Keys wider than 32 bits that are not
+packed (the dataplane's wide keys) run as ``K`` stacked int32 word arrays:
+the block sort kernel :func:`sort_tiles_stacked` and the tournament's XLA
+form :func:`tournament_merge_stacked` take one compare-exchange decision
+per pair, lexicographic over the words (:func:`lex_gt_lt`), and move every
+word with it.  The Pallas kernels never reshape the
 lane axis or reverse a value — Mosaic lowers neither — so in a kernel a
 stage finds each element's partner with two ``pltpu.roll`` rotations and
 an ``iota`` index mask (:func:`_network`), and pairs whole rows by aligned
@@ -52,11 +57,12 @@ def _merge_stages(n: int):
 
 
 def _reverse_odd_rows(x: jax.Array) -> jax.Array:
-    """Sorted runs as rows, odd rows reversed: adjacent row pairs are then
-    bitonic, which is the state a bitonic sort reaches after its rounds
-    k <= B.  Done in XLA: Pallas TPU has no lowering for ``lax.rev``."""
-    odd = (jnp.arange(x.shape[0]) % 2 == 1)[:, None]
-    return jnp.where(odd, jnp.flip(x, axis=1), x)
+    """Sorted runs as rows (the last two axes), odd rows reversed: adjacent
+    row pairs are then bitonic, which is the state a bitonic sort reaches
+    after its rounds k <= B.  Done in XLA: Pallas TPU has no lowering for
+    ``lax.rev``."""
+    odd = (jnp.arange(x.shape[-2]) % 2 == 1)[:, None]
+    return jnp.where(odd, jnp.flip(x, axis=-1), x)
 
 
 def tournament_merge_array(x: jax.Array) -> jax.Array:
@@ -93,6 +99,34 @@ def tournament_merge_array(x: jax.Array) -> jax.Array:
     return jax.lax.fori_loop(0, len(stages), stage, v)
 
 
+def tournament_merge_stacked(x: jax.Array) -> jax.Array:
+    """:func:`tournament_merge_array` for ``K`` stacked word arrays
+    ``(K, P, B)``: every compare-exchange is lexicographic over the ``K``
+    words (word 0 most significant) and moves all of them.  Returns the
+    merged ``(K, P*B)`` words.  Pads are all-``dtype max`` tuples."""
+    K, P, B = x.shape
+    if P & (P - 1) or B & (B - 1):
+        raise ValueError(f"tournament shape must be powers of two, got {x.shape}")
+    _check_integer(x.dtype)
+    n = P * B
+    v = _reverse_odd_rows(x).reshape(K, n)
+    stages = [(k, j) for k, j in _stages(n) if k > B]
+    if not stages:
+        return v
+    ks, js = (jnp.asarray(col, jnp.int32) for col in zip(*stages))
+    g = jax.lax.iota(jnp.int32, n)
+
+    def stage(s, v):
+        k, j = ks[s], js[s]
+        lower = (g & j) == 0
+        p = jnp.where(lower, jnp.roll(v, -j, axis=1), jnp.roll(v, j, axis=1))
+        take_min = lower == ((g & k) == 0)
+        gt, lt = lex_gt_lt(tuple(v), tuple(p))
+        return jnp.where(jnp.where(take_min, ~gt, ~lt), v, p)
+
+    return jax.lax.fori_loop(0, len(stages), stage, v)
+
+
 def _check_integer(dtype) -> None:
     if jnp.dtype(dtype).kind not in "iu":
         # The row pads are the dtype max; only integer keys have a total
@@ -123,16 +157,30 @@ def _partner(xs, g, j: int, shift: int, axis: int):
     )
 
 
-def _network(xs, g, stages, width: int):
-    """Apply compare-exchange ``stages`` to the keys ``xs[0]`` (and the
-    values riding with them, ``xs[1:]``) of one block.
+def lex_gt_lt(a, b):
+    """Lexicographic ``a > b`` and ``a < b`` over the word tuples ``a`` and
+    ``b`` (first word most significant), from elementwise compares alone."""
+    gt = a[-1] > b[-1]
+    lt = a[-1] < b[-1]
+    for x, y in zip(a[-2::-1], b[-2::-1]):
+        eq = x == y
+        gt = (x > y) | (eq & gt)
+        lt = (x < y) | (eq & lt)
+    return gt, lt
+
+
+def _network(xs, g, stages, width: int, num_keys: int = 1):
+    """Apply compare-exchange ``stages`` to the keys ``xs[:num_keys]`` (and
+    the values riding with them, ``xs[num_keys:]``) of one block.
 
     ``g`` is the flat network index of each element of the block, laid out
     row-major with ``width`` lanes per row: a stride below ``width`` pairs
     lanes within a row, a larger one pairs rows.  The direction of each
-    pair ascends iff ``(g & k) == 0``.  Keys alone take the pair's
-    min/max; with values, the keys' swap decision moves both (the result
-    is the same as the XLA form's, element for element).
+    pair ascends iff ``(g & k) == 0``.  One key alone takes the pair's
+    min/max; otherwise the keys' swap decision, lexicographic over the
+    ``num_keys`` key arrays (the first most significant), moves every
+    array (for one key the result is the same as the XLA form's, element
+    for element).
     """
     for k, j in stages:
         if j < width:
@@ -140,15 +188,18 @@ def _network(xs, g, stages, width: int):
         else:
             p = _partner(xs, g, j, j // width, 0)
         lower = (g & j) == 0
-        lo = jnp.where(lower, xs[0], p[0])
-        hi = jnp.where(lower, p[0], xs[0])
+        lo = tuple(jnp.where(lower, x, q) for x, q in zip(xs[:num_keys], p))
+        hi = tuple(jnp.where(lower, q, x) for x, q in zip(xs[:num_keys], p))
         if len(xs) == 1:
             take_min = ((g >> (j.bit_length() - 1)) ^ (g >> (k.bit_length() - 1))) & 1 == 0
-            xs = (jnp.where(take_min, jnp.minimum(lo, hi), jnp.maximum(lo, hi)),)
+            xs = (jnp.where(
+                take_min, jnp.minimum(lo[0], hi[0]), jnp.maximum(lo[0], hi[0])
+            ),)
         else:
             asc = (g & k) == 0
+            gt, lt = lex_gt_lt(lo, hi)
             # where() over booleans has no Mosaic lowering; and/or does.
-            swap = (asc & (lo > hi)) | (~asc & (lo < hi))
+            swap = (asc & gt) | (~asc & lt)
             xs = tuple(jnp.where(swap, q, x) for x, q in zip(xs, p))
     return xs
 
@@ -180,6 +231,44 @@ def _rows_call(xs, stages, rows_per_tile: int, interpret: bool):
         out_specs=tuple([spec] * len(xs)),
         interpret=interpret,
     )(*xs)
+
+
+def _stacked_rows_kernel(x_ref, o_ref, *, stages):
+    """Independent rows of ``K`` stacked word arrays, sorted lexicographically
+    over all ``K`` (word 0 most significant)."""
+    xs = tuple(x_ref[i] for i in range(x_ref.shape[0]))
+    width = xs[0].shape[1]
+    out = _network(xs, _lane_index(xs[0].shape), stages, width, len(xs))
+    for i, v in enumerate(out):
+        o_ref[i] = v
+
+
+def sort_tiles_stacked(
+    x: jax.Array,
+    *,
+    rows_per_tile: int = 8,
+    interpret: bool = True,
+) -> jax.Array:
+    """Sort each row of the ``K`` stacked arrays ``x`` (K, rows, B) as one
+    sequence of ``K``-word tuples, compared lexicographically with word 0
+    most significant: the multi-word block sort.
+
+    One input and one output, so a profiler's result shape holds every
+    word.  BlockSpec tiles (K, rows_per_tile, B) into VMEM; B power of two.
+    """
+    K, rows, n = x.shape
+    _check_pow2(n)
+    if rows % rows_per_tile:
+        raise ValueError(f"rows {rows} % rows_per_tile {rows_per_tile} != 0")
+    spec = pl.BlockSpec((K, rows_per_tile, n), lambda i: (0, i, 0))
+    return pl.pallas_call(
+        functools.partial(_stacked_rows_kernel, stages=tuple(_stages(n))),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        grid=(rows // rows_per_tile,),
+        in_specs=[spec],
+        out_specs=spec,
+        interpret=interpret,
+    )(x)
 
 
 def _check_pow2(n: int) -> None:

@@ -11,7 +11,11 @@ Every sort or merge entry point runs one of three lowerings:
 * ``"xla"`` — the plain-jnp network or ``jnp.sort``.  This is the dtype
   rule: 64-bit keys (the dataplane's packed key+row records) never enter a
   Pallas kernel, on any backend.  Off-TPU the run-arena tournament also
-  takes it, since interpreting its kernel is orders of magnitude slower.
+  takes it, since interpreting its kernel is orders of magnitude slower,
+  and the multi-word tournament takes it on every backend.
+
+Wide keys enter as ``K`` stacked 32-bit word arrays (a 3-D argument to
+:func:`sort_rows_padded` and :func:`merge_tournament`), compared as tuples.
 
 :data:`LOWERING_COUNTS` records each call's choice, so a run can show that
 no call on the chip fell back to interpret mode or to XLA.
@@ -31,11 +35,13 @@ from . import bitonic
 from .flash_attention import flash_attention as _flash_attention
 
 #: Calls of the sort/merge entry points by ``"<op>:<lowering>:<key
-#: dtype>"``.  A call made while a larger program is traced (the device
+#: dtype>"``, ``<dtype>x<K>`` for a multi-word call on ``K`` stacked word
+#: arrays.  A call made while a larger program is traced (the device
 #: epoch's block sort) counts once per trace, not once per execution.  The
 #: device epoch also counts each hop it traces, as
 #: ``"epoch_hop:scatter_free:int64"`` (the hop's stage math lowers without
-#: a scatter).
+#: a scatter), and a wide-key hop of ``W`` sort words as
+#: ``"epoch_hop:wide_key:w<W>"`` besides.
 LOWERING_COUNTS: collections.Counter = collections.Counter()
 
 
@@ -67,8 +73,11 @@ def _kernel_scope(lowering: str):
     return jax.enable_x64(False)
 
 
-def _lowering(dtype, interpret: bool | None, op: str) -> str:
-    """Pick and count the lowering of one call (module docstring)."""
+def _lowering(dtype, interpret: bool | None, op: str, words: int = 0) -> str:
+    """Pick and count the lowering of one call (module docstring).
+
+    ``words`` > 0 marks a multi-word call on that many stacked arrays,
+    counted as ``<dtype>x<words>``."""
     dtype = np.dtype(dtype)
     if dtype.itemsize == 8:
         lowering = "xla"
@@ -76,7 +85,8 @@ def _lowering(dtype, interpret: bool | None, op: str) -> str:
         lowering = "interpret" if _interpret_default(interpret) else "pallas"
     if op == "merge_tournament" and lowering == "interpret":
         lowering = "xla"
-    LOWERING_COUNTS[f"{op}:{lowering}:{dtype.name}"] += 1
+    name = f"{dtype.name}x{words}" if words else dtype.name
+    LOWERING_COUNTS[f"{op}:{lowering}:{name}"] += 1
     return lowering
 
 
@@ -159,6 +169,14 @@ def sort_rows_padded(x: jax.Array, interpret: bool | None = None) -> jax.Array:
     """Row sort for an arbitrary row count: the fused hop engine's one
     device call per switch hop, and the device epoch's block sort.
 
+    A 3-D ``x`` of ``K`` stacked 32-bit word arrays ``(K, rows, B)`` is the
+    multi-word entry: each row sorts as ``K``-word tuples compared
+    lexicographically (word 0 most significant; the device epoch's wide
+    keys, with the input row as the last word), in the Pallas kernel
+    (:func:`repro.kernels.bitonic.sort_tiles_stacked`), counted as
+    ``sort_rows_padded:<lowering>:int32x<K>``; its pad rows are all-max
+    tuples.
+
     Pads the row dimension up to a multiple of 8 with dtype-max rows so the
     grid always tiles 8 rows per kernel invocation (``sort_rows`` would fall
     to 1-row tiles whenever the row count is prime), sorts, and slices the
@@ -171,12 +189,37 @@ def sort_rows_padded(x: jax.Array, interpret: bool | None = None) -> jax.Array:
     kernel's, element for element.
     """
     _check_sort_keys(x, "sort_rows_padded")
-    b = x.shape[1]
+    b = x.shape[-1]
     if b & (b - 1):
         raise ValueError(f"column count must be a power of two, got {b}")
+    if x.ndim == 3:
+        _check_words(x, "sort_rows_padded")
+        lowering = _lowering(x.dtype, interpret, "sort_rows_padded", x.shape[0])
+        with _kernel_scope(lowering):
+            return _sort_rows_padded_stacked(x, lowering=lowering)
     lowering = _lowering(x.dtype, interpret, "sort_rows_padded")
     with _kernel_scope(lowering):
         return _sort_rows_padded(x, lowering=lowering)
+
+
+def _check_words(x, op: str) -> None:
+    if np.dtype(x.dtype).itemsize != 4:
+        raise TypeError(f"{op}: stacked words are 32-bit, got {x.dtype}")
+
+
+@functools.partial(jax.jit, static_argnames=("lowering",))
+def _sort_rows_padded_stacked(x: jax.Array, lowering: str) -> jax.Array:
+    K, rows, b = x.shape
+    if rows == 0:
+        return x
+    pad = (-rows) % 8
+    if pad:
+        fill = jnp.full((K, pad, b), jnp.iinfo(x.dtype).max, x.dtype)
+        x = jnp.concatenate([x, fill], axis=1)
+    out = bitonic.sort_tiles_stacked(
+        x, rows_per_tile=8, interpret=lowering == "interpret"
+    )
+    return out[:, :rows]
 
 
 @functools.partial(jax.jit, static_argnames=("lowering",))
@@ -243,7 +286,9 @@ def _merge_rows(a: jax.Array, b: jax.Array, interpret: bool) -> jax.Array:
 
 def merge_tournament(x: jax.Array, interpret: bool | None = None) -> jax.Array:
     """Merge ``P`` padded sorted rows (P, B) into one sorted (P*B,) stream —
-    the run-arena engine's one device call per segment.
+    the run-arena engine's one device call per segment.  A 3-D ``x`` of
+    ``K`` stacked 32-bit word arrays ``(K, P, B)`` merges as ``K``-word
+    tuples into ``(K, P*B)`` (:func:`_merge_stacked`).
 
     Rows are runs padded with the dtype max (pads stay at row tails through
     every round and are sliced off by the caller); each round merges adjacent
@@ -262,9 +307,11 @@ def merge_tournament(x: jax.Array, interpret: bool | None = None) -> jax.Array:
     tournament_merge_array`).
     """
     _check_sort_keys(x, "merge_tournament")
-    P, B = x.shape
+    P, B = x.shape[-2:]
     if P & (P - 1) or B & (B - 1):
         raise ValueError(f"tournament shape must be powers of two, got {x.shape}")
+    if x.ndim == 3:
+        return _merge_stacked(x)
     lowering = _lowering(x.dtype, interpret, "merge_tournament")
     if lowering == "pallas" and P * B > bitonic.TOURNAMENT_MAX_ELEMS:
         raise ValueError(
@@ -273,6 +320,23 @@ def merge_tournament(x: jax.Array, interpret: bool | None = None) -> jax.Array:
         )
     with _kernel_scope(lowering):
         return _merge_tournament(x, lowering=lowering)
+
+
+def _merge_stacked(x: jax.Array) -> jax.Array:
+    """The multi-word tournament: ``K`` stacked 32-bit word arrays
+    ``(K, P, B)`` merged as ``K``-word tuples into ``(K, P*B)``, in the XLA
+    form on every backend: a Pallas kernel of its unrolled rounds, with
+    every compare-exchange ``K`` words wide, took seconds to trace in each
+    process on a v5e, more than the XLA form's added device time in a
+    whole run (PERF.md, Findings)."""
+    _check_words(x, "merge_tournament")
+    LOWERING_COUNTS[f"merge_tournament:xla:{x.dtype.name}x{x.shape[0]}"] += 1
+    return _merge_tournament_stacked(x)
+
+
+@jax.jit
+def _merge_tournament_stacked(x: jax.Array) -> jax.Array:
+    return bitonic.tournament_merge_stacked(x)
 
 
 @functools.partial(jax.jit, static_argnames=("lowering",))

@@ -63,6 +63,14 @@ the host then replays the bookkeeping — metrics counters and the
 :class:`~repro.net.wire.WireBatch` objects, so the timing overlay sees
 exactly what the per-hop loop would have shown it.
 
+Wide keys (9 to 16 bytes, :func:`repro.net.pipeline.run_pipeline`) build
+their own program from a wide range table: each key rides as ``W`` int32
+sort words with its input row as the last sort word, route counts the
+range bounds at or below the key lexicographically, the block sort is the
+multi-word Pallas kernel, ship gathers every word column, and run breaks
+compare the word tuples (:func:`_device_hop_wide`).  Rank and emit are the
+one-word hop's own code.
+
 The egress result is a :class:`DeviceDelivery`: a wire batch that also
 carries the segment-grouped emission streams and their run-break flags, so
 the server pool can feed each segment's run arena directly
@@ -113,7 +121,9 @@ class DeviceDelivery(WireBatch):
     start flags the device already computed for the hop statistics.  Any
     row gather (``take``/``slice_keys``/jitter) degrades to a plain
     :class:`WireBatch`, which makes the pool's fast-path condition a simple
-    ``isinstance``-free ``getattr`` check.
+    ``isinstance``-free ``getattr`` check.  For wide keys ``values`` is the
+    ``(n, W)`` sort-word matrix and ``grouped_values`` the ``(n, W + 1)``
+    words with each key's input row as the last column.
     """
 
     grouped_values: np.ndarray | None = None
@@ -333,6 +343,10 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
 
     from ..kernels import ops
 
+    if isinstance(vals, tuple):
+        return _device_hop_wide(
+            vals, rows, bounds, S, L, P, vbits, use_kernel, interpret
+        )
     i64 = jnp.int64
     n = int(vals.shape[0])
     packable = vbits > 0
@@ -340,17 +354,7 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
     with jax.named_scope("route"):
         seg = jnp.searchsorted(bounds, vals, side="right").astype(i64)
     with jax.named_scope("rank"):
-        if packable:
-            order, seg_g = _stable_perm(seg, n)
-        else:
-            order = jnp.argsort(seg, stable=True)
-            seg_g = seg[order]
-        # seg_g is sorted: each segment's edges are searches into it.
-        edges = jnp.searchsorted(
-            seg_g, jnp.arange(S + 1, dtype=i64), side="left"
-        ).astype(i64)
-        starts, counts = edges[:-1], edges[1:] - edges[:-1]
-        q = jnp.arange(n, dtype=i64) - starts[seg_g]  # in-segment position
+        order, seg_g, starts, counts, q = _rank(seg, n, S, packable)
         grouped = vals[order]
 
     # -- block sort: rows of one padded (R, L) matrix -------------------
@@ -405,34 +409,16 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
 
     # -- wire order: packets ship at their last key's emission ----------
     with jax.named_scope("ship"):
-        pkt_j = q // P
-        last_q = jnp.minimum((pkt_j + 1) * P, counts[seg_g]) - 1
-        ship_key = eidx[jnp.clip(starts[seg_g] + last_q, 0, max(n - 1, 0))]
-        if packable:
-            out_perm, _ = _stable_perm(ship_key, n)  # ship index < n: fits
-        else:
-            out_perm = jnp.argsort(ship_key, stable=True)
+        out_perm, ship_key, pkt_j = _ship_order(
+            eidx, seg_g, q, counts, starts, n, P, packable
+        )
         vals_out = stream[out_perm]
         sid_out = seg_g[out_perm]
         seq_out = pkt_j[out_perm]
 
-        # -- per-key packet ordinal (the next hop's round-robin turn) ---
-        if n:
-            chg = jnp.concatenate([
-                jnp.ones(1, bool),
-                (seq_out[1:] != seq_out[:-1]) | (sid_out[1:] != sid_out[:-1]),
-            ])
-            turn = jnp.cumsum(chg).astype(i64) - 1
-            seg_chg = jnp.concatenate(
-                [jnp.ones(1, bool), seg_g[1:] != seg_g[:-1]]
-            )
-            desc = jnp.concatenate(
-                [jnp.zeros(1, bool), stream[1:] < stream[:-1]]
-            )
-            brk = seg_chg | desc
-        else:
-            turn = jnp.zeros(0, i64)
-            brk = jnp.zeros(0, bool)
+        turn, brk = _turns_and_breaks(
+            seq_out, sid_out, seg_g, lambda: stream[1:] < stream[:-1], n
+        )
 
         hop = {
             "vals": vals_out,
@@ -449,6 +435,142 @@ def _device_hop(vals, rows, bounds, S: int, L: int, P: int,
             hop["rows"] = stream_rows[out_perm]
             hop["stream_rows"] = stream_rows
     return hop
+
+
+def _rank(seg, n: int, S: int, packable: bool):
+    """The rank stage: the stable grouping permutation by segment, the
+    grouped segment column, each segment's start and count, and each
+    grouped slot's in-segment position."""
+    import jax.numpy as jnp
+
+    i64 = jnp.int64
+    if packable:
+        order, seg_g = _stable_perm(seg, n)
+    else:
+        order = jnp.argsort(seg, stable=True)
+        seg_g = seg[order]
+    # seg_g is sorted: each segment's edges are searches into it.
+    edges = jnp.searchsorted(
+        seg_g, jnp.arange(S + 1, dtype=i64), side="left"
+    ).astype(i64)
+    starts, counts = edges[:-1], edges[1:] - edges[:-1]
+    q = jnp.arange(n, dtype=i64) - starts[seg_g]  # in-segment position
+    return order, seg_g, starts, counts, q
+
+
+def _ship_order(eidx, seg_g, q, counts, starts, n: int, P: int,
+                packable: bool):
+    """The ship stage's wire order: packets ship at their last key's
+    emission.  Returns the permutation, the per-key ship indices and
+    packet ordinals in grouped order."""
+    import jax.numpy as jnp
+
+    pkt_j = q // P
+    last_q = jnp.minimum((pkt_j + 1) * P, counts[seg_g]) - 1
+    ship_key = eidx[jnp.clip(starts[seg_g] + last_q, 0, max(n - 1, 0))]
+    if packable:
+        out_perm, _ = _stable_perm(ship_key, n)  # ship index < n: fits
+    else:
+        out_perm = jnp.argsort(ship_key, stable=True)
+    return out_perm, ship_key, pkt_j
+
+
+def _turns_and_breaks(seq_out, sid_out, seg_g, descents, n: int):
+    """Each wire key's packet ordinal (the next hop's round-robin turn) and
+    the grouped stream's run-break flags: a new segment, or a key below the
+    one before it (``descents()``, ``stream[1:] < stream[:-1]``)."""
+    import jax.numpy as jnp
+
+    i64 = jnp.int64
+    if not n:
+        return jnp.zeros(0, i64), jnp.zeros(0, bool)
+    chg = jnp.concatenate([
+        jnp.ones(1, bool),
+        (seq_out[1:] != seq_out[:-1]) | (sid_out[1:] != sid_out[:-1]),
+    ])
+    turn = jnp.cumsum(chg).astype(i64) - 1
+    seg_chg = jnp.concatenate([jnp.ones(1, bool), seg_g[1:] != seg_g[:-1]])
+    desc = jnp.concatenate([jnp.zeros(1, bool), descents()])
+    return turn, seg_chg | desc
+
+
+def _device_hop_wide(words, rows, bounds, S: int, L: int, P: int,
+                     vbits: int, use_kernel: bool, interpret: bool | None):
+    """:func:`_device_hop` for wide keys: ``words`` is a tuple of ``W``
+    int32 sort-word columns (:func:`repro.core.partition.key_words`),
+    ``rows`` the int32 input rows, ``bounds`` the ``(W, S - 1)`` words of
+    the inner range bounds.
+
+    The rows ride as the last sort word, so every order below is the
+    lexicographic order of ``(words..., row)`` tuples: ties resolve by
+    input row, and the all-max pad tuple sorts above every real one.
+    Route counts the inner bounds at or below each key; rank, emit and
+    ship are the one-word hop's; the block sort is the multi-word Pallas
+    kernel (``lax.sort`` over the tuples without it); run breaks compare
+    the tuples.  Each traced hop counts ``epoch_hop:wide_key:w<W>`` beside
+    ``epoch_hop:scatter_free:int64``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels import ops
+    from ..kernels.bitonic import lex_gt_lt
+
+    i64 = jnp.int64
+    n = int(words[0].shape[0])
+    packable = vbits > 0
+    W = len(words)
+    ops.LOWERING_COUNTS["epoch_hop:scatter_free:int64"] += 1
+    ops.LOWERING_COUNTS[f"epoch_hop:wide_key:w{W}"] += 1
+    with jax.named_scope("route"):
+        seg = jnp.zeros(n, i64)
+        for b in range(S - 1):
+            _, below = lex_gt_lt(words, tuple(bounds[:, b]))
+            seg = seg + (~below).astype(i64)
+    with jax.named_scope("rank"):
+        order, seg_g, starts, counts, q = _rank(seg, n, S, packable)
+        grouped = tuple(w[order] for w in words) + (rows[order],)
+
+    with jax.named_scope("block_sort"):
+        blocks = _BlockRows(seg_g, counts, starts, S, L)
+        pad = jnp.iinfo(jnp.int32).max
+        mats = tuple(blocks.to_rows(c, pad) for c in grouped)
+        if use_kernel:
+            srt = ops.sort_rows_padded(jnp.stack(mats), interpret=interpret)
+            srt = tuple(srt[i] for i in range(W + 1))
+        else:
+            srt = jax.lax.sort(mats, dimension=1, num_keys=W + 1)
+        stream = tuple(blocks.from_rows(m) for m in srt)
+
+    with jax.named_scope("emit"):
+        eidx = _emit_index(order, seg_g, q, counts, starts, L)
+
+    with jax.named_scope("ship"):
+        out_perm, ship_key, pkt_j = _ship_order(
+            eidx, seg_g, q, counts, starts, n, P, packable
+        )
+        wire = tuple(c[out_perm] for c in stream)
+        sid_out = seg_g[out_perm]
+        seq_out = pkt_j[out_perm]
+        turn, brk = _turns_and_breaks(
+            seq_out, sid_out, seg_g,
+            lambda: lex_gt_lt(
+                tuple(c[1:] for c in stream), tuple(c[:-1] for c in stream)
+            )[1],
+            n,
+        )
+        return {
+            "vals": wire[:W],
+            "rows": wire[W],
+            "seq": seq_out,
+            "sid": sid_out,
+            "turn": turn,
+            "ship": ship_key[out_perm],
+            "counts": counts,
+            "runs": brk.sum().astype(i64),
+            "stream": stream,
+            "brk": brk,
+        }
 
 
 def _rr_merge(parts, carry_rows: bool, packable: bool):
@@ -471,7 +593,13 @@ def _rr_merge(parts, carry_rows: bool, packable: bool):
         order, _ = _stable_perm(turn, m)
     else:
         order = jnp.argsort(turn, stable=True)
-    vals = jnp.concatenate([p["vals"] for p in parts])[order]
+    if isinstance(parts[0]["vals"], tuple):  # wide keys: word columns
+        vals = tuple(
+            jnp.concatenate([p["vals"][i] for p in parts])[order]
+            for i in range(len(parts[0]["vals"]))
+        )
+    else:
+        vals = jnp.concatenate([p["vals"] for p in parts])[order]
     rows = None
     if carry_rows:
         rows = jnp.concatenate([p["rows"] for p in parts])[order]
@@ -490,8 +618,8 @@ def _epoch_program(graph, spec: HopSpec, ranges: np.ndarray,
     """
     key = (
         graph, spec.num_segments, spec.segment_length, spec.payload_size,
-        ranges.tobytes(), group_ns, carry_rows, use_kernel, interpret,
-        taps,
+        ranges.dtype.str, ranges.tobytes(), group_ns, carry_rows,
+        use_kernel, interpret, taps,
     )
     fn = _PROGRAM_CACHE.get(key)
     if fn is None:
@@ -510,13 +638,22 @@ def _build_program(graph, spec: HopSpec, ranges: np.ndarray,
                    group_ns: tuple, carry_rows: bool, use_kernel: bool,
                    interpret: bool | None, taps: bool):
     """The jitted whole-epoch program: each hop in a ``hop_<name>`` named
-    scope, each uplink merge in ``uplink_merge``."""
+    scope, each uplink merge in ``uplink_merge``.
+
+    A wide-key table (:func:`_is_wide`) builds the wide program: its
+    ingress values are ``(W, n)`` sort words, its rows int32, and it
+    returns the egress words stacked ``(W, n)`` and the grouped stream
+    stacked with its rows ``(W + 1, n)``."""
     import jax
     import jax.numpy as jnp
 
     S, L, P = spec.num_segments, spec.segment_length, spec.payload_size
-    bounds_np = np.ascontiguousarray(ranges[:, 1], dtype=np.int64)
     nodes = graph.nodes
+    if _is_wide(ranges):
+        return _build_wide_program(
+            nodes, S, L, P, ranges, group_ns, use_kernel, interpret
+        )
+    bounds_np = np.ascontiguousarray(ranges[:, 1], dtype=np.int64)
     # Packed-sort feasibility: every stable permutation in the epoch rides
     # a key-only sort of ``(key << bits(n)) | index`` when the domain is
     # non-negative and key+index fit in 63 bits; otherwise vbits=0 selects
@@ -574,6 +711,60 @@ def _build_program(graph, spec: HopSpec, ranges: np.ndarray,
                 for h in hops
             )
         return res
+
+    return jax.jit(epoch_fn, donate_argnums=(0, 1))
+
+
+def _is_wide(ranges: np.ndarray) -> bool:
+    """Whether ``ranges`` is a wide-key table: the int32 sort words of
+    each range's lower bound (:func:`repro.core.partition.set_ranges_wide`),
+    not int64 ``[lo, hi)`` pairs."""
+    return ranges.dtype == np.int32
+
+
+def _build_wide_program(nodes, S: int, L: int, P: int, ranges: np.ndarray,
+                        group_ns: tuple, use_kernel: bool,
+                        interpret: bool | None):
+    """:func:`_build_program` for wide keys (:func:`_device_hop_wide`)."""
+    import jax
+    import jax.numpy as jnp
+
+    bounds_np = np.ascontiguousarray(ranges[1:].T)  # (W, S - 1)
+    W = bounds_np.shape[0]
+    n_total = int(sum(group_ns))
+    vbits = 32 if n_total <= 1 << 31 else 0
+
+    def epoch_fn(ingress_vals, ingress_rows):
+        bounds = jnp.asarray(bounds_np)
+        hops = []
+        for node in nodes:
+            with jax.named_scope(f"hop_{node.name}"):
+                if node.parents:
+                    with jax.named_scope("uplink_merge"):
+                        vals, rows = _rr_merge(
+                            [hops[p] for p in node.parents], True, vbits > 0
+                        )
+                else:
+                    g = ingress_vals[node.group]
+                    vals = tuple(g[i] for i in range(W))
+                    rows = ingress_rows[node.group]
+                hops.append(
+                    _device_hop(
+                        vals, rows, bounds, S, L, P, vbits, use_kernel,
+                        interpret,
+                    )
+                )
+        eg = hops[-1]
+        return {
+            "vals": jnp.stack(eg["vals"]),
+            "rows": eg["rows"],
+            "seq": eg["seq"],
+            "sid": eg["sid"],
+            "counts": tuple(h["counts"] for h in hops),
+            "runs": tuple(h["runs"] for h in hops),
+            "stream": jnp.stack(eg["stream"]),
+            "brk": eg["brk"],
+        }
 
     return jax.jit(epoch_fn, donate_argnums=(0, 1))
 
@@ -657,33 +848,55 @@ def run_graph_device(
     ranges = spec.ranges
     if ranges is None:
         ranges = set_ranges(spec.max_value, spec.num_segments)
-
-    # Domain check once at ingress (interior hops see the same multiset).
-    vmin = int(batch.values.min())
-    vmax = int(batch.values.max())
-    if vmin < int(ranges[0, 0]) or vmax >= int(ranges[-1, 1]):
-        raise ValueError("value outside the switch domain")
     L = spec.segment_length
-    use_kernel = (
-        spec.backend == "pallas"
-        and not carry_rows
-        and L > 1
-        and not (L & (L - 1))
-        and vmin >= 0
-        and vmax < np.iinfo(np.int32).max
-    )
+    pow2_blocks = L > 1 and not (L & (L - 1))
+    wide = _is_wide(ranges)
+    if wide:
+        # Wide keys: every key of B bytes lies in the table's domain; the
+        # rows ride as the last sort word, so the Pallas block sort takes
+        # them as it takes the words.
+        if batch.values.ndim != 2 or not carry_rows:
+            raise ValueError(
+                "a wide-key table needs sort-word values and their rows"
+            )
+        if collect:
+            raise ValueError(
+                "wide keys run the device epoch unobserved: no metrics, "
+                "network or per-hop taps"
+            )
+        use_kernel = spec.backend == "pallas" and pow2_blocks
+    else:
+        # Domain check once at ingress (interior hops see the same
+        # multiset).
+        vmin = int(batch.values.min())
+        vmax = int(batch.values.max())
+        if vmin < int(ranges[0, 0]) or vmax >= int(ranges[-1, 1]):
+            raise ValueError("value outside the switch domain")
+        use_kernel = (
+            spec.backend == "pallas"
+            and not carry_rows
+            and pow2_blocks
+            and vmin >= 0
+            and vmax < np.iinfo(np.int32).max
+        )
 
     with x64_scope():
         # ``epoch:put`` holds the whole ingress layout: the split into
         # ingress groups and the one copy of their columns.
         with tr.span("epoch:put", cat="epoch") as put:
             ingress = split_by_flow(batch, graph.num_groups)
-            host_args = (
-                tuple(np.ascontiguousarray(g.values) for g in ingress),
-                tuple(np.ascontiguousarray(g.row_index) for g in ingress)
-                if carry_rows
-                else (),
-            )
+            if wide:  # word columns as rows of one (W, n) array
+                host_args = (
+                    tuple(np.ascontiguousarray(g.values.T) for g in ingress),
+                    tuple(g.row_index.astype(np.int32) for g in ingress),
+                )
+            else:
+                host_args = (
+                    tuple(np.ascontiguousarray(g.values) for g in ingress),
+                    tuple(np.ascontiguousarray(g.row_index) for g in ingress)
+                    if carry_rows
+                    else (),
+                )
             put.set(bytes=nbytes(host_args))
             dev_args = to_device(host_args)
         fn = _epoch_program(
@@ -709,17 +922,17 @@ def run_graph_device(
                 )
                 for i, node in enumerate(graph.nodes)
             ]
+            # Wide keys come back stacked by column: the transposes are
+            # (n, W) views, no copy.
+            vals = res["vals"].T if wide else res["vals"]
             delivery = DeviceDelivery(
-                res["vals"],
-                np.full(
-                    int(res["vals"].size), len(graph.nodes) - 1,
-                    dtype=np.int64,
-                ),
+                vals,
+                np.full(len(vals), len(graph.nodes) - 1, dtype=np.int64),
                 res["seq"],
                 res["sid"],
                 epoch=batch.epoch,
                 row_index=res.get("rows"),
-                grouped_values=res["stream"],
+                grouped_values=res["stream"].T if wide else res["stream"],
                 grouped_rows=res.get("stream_rows"),
                 seg_counts=np.asarray(res["counts"][-1], dtype=np.int64),
                 run_flags=res["brk"],

@@ -428,7 +428,7 @@ class ServerPool:
                 "grouped handoff supports single-epoch pools only"
             )
         values = np.asarray(values)
-        if values.size == 0:
+        if len(values) == 0:
             return
         seg_counts = np.asarray(seg_counts, dtype=np.int64)
         if seg_counts.size != self.eff_segments:
@@ -436,7 +436,7 @@ class ServerPool:
                 f"seg_counts length {seg_counts.size} != "
                 f"{self.eff_segments} segments"
             )
-        if int(seg_counts.sum()) != int(values.size):
+        if int(seg_counts.sum()) != len(values):
             raise ValueError("seg_counts do not sum to the stream length")
         bounds = np.concatenate([[0], np.cumsum(seg_counts)])
         flags = np.asarray(run_flags, dtype=bool)

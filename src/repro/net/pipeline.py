@@ -45,10 +45,18 @@ import dataclasses
 
 import numpy as np
 
+from repro.hostmem import keep_freed_memory
 from repro.obs.telemetry import int_summary
 from repro.obs.trace import NULL_TRACER
 
-from ..core.partition import quantile_ranges, set_ranges
+from ..core.partition import (
+    WIDE_KEY_BYTES,
+    key_words,
+    quantile_ranges,
+    set_ranges,
+    set_ranges_wide,
+    words_to_keys,
+)
 from .control import RANGE_MODES, AdaptiveControlPlane, ControlPlane, ranges_valid
 from .egress import ServerPool
 from .engine import HopStats
@@ -261,9 +269,58 @@ def run_pipeline(
     column (``fused`` and ``device`` engines only), the server sorts keys
     packed with their row (ties resolve by arrival order, i.e. a stable
     sort), and the table is gathered exactly once at egress into
-    ``PipelineResult.sorted_payload``.  The key domain must leave room for
-    the row bits: ``max_value < 2**(63 - ceil(log2(n)))``.
+    ``PipelineResult.sorted_payload``.  For integer keys the key domain
+    must leave room for the row bits: ``max_value < 2**(63 -
+    ceil(log2(n)))``.
+
+    Wide keys: ``values`` may be an ``(n, B)`` uint8 array of keys of ``B``
+    = 9 to 16 bytes, ordered as unsigned bytes (memcmp, the Sort
+    Benchmark's order), with ``payload`` an ``(n, P)`` array.  They run on
+    ``engine="device"`` with ``merge_backend="arena"`` and Alg. 2's static
+    equal-width ranges over ``[0, 256**B)``
+    (:func:`repro.core.partition.set_ranges_wide`) alone; every other
+    engine, range source and observer refuses them
+    (:func:`_run_pipeline_wide`).  Each key rides as ``ceil(B/4)`` int32
+    sort words (:func:`repro.core.partition.key_words`) with its input row
+    as the last sort word, so ties resolve by input row (a stable sort).
+    ``output`` holds the sorted keys, ``(n, B)`` uint8; ``sorted_payload``
+    the payload rows in that order, gathered once on the host in the span
+    ``pipeline:payload``; ``delivered`` the wire with the words as its
+    ``(n, W)`` values and each key's ``row_index`` and ``segment_id``.  A
+    wide-key job sets the process's allocator to keep the memory it frees
+    (:func:`repro.hostmem.keep_freed_memory`), so the next job reuses its
+    host columns' pages.
     """
+    values = np.asarray(values)
+    if values.ndim == 2:
+        return _run_pipeline_wide(
+            values,
+            topology=topology, num_flows=num_flows, payload_size=payload_size,
+            num_segments=num_segments, segment_length=segment_length,
+            interleave_mode=interleave_mode, seed=seed, backend=backend,
+            k=k, num_servers=num_servers, pool_backend=pool_backend,
+            reorder_capacity=reorder_capacity, payload=payload,
+            verify=verify, tracer=tracer, topo_kw=topo_kw,
+            refused={
+                "engine": (
+                    (engine or "fused") if engine != "device" else None
+                ),
+                "faithful": faithful or None,
+                "merge_backend": (
+                    merge_backend if merge_backend != "arena" else None
+                ),
+                "range_mode": range_mode if range_mode != "static" else None,
+                "max_value": max_value,
+                "control": control,
+                "adaptive": adaptive,
+                "network": network,
+                "recovery": recovery or None,
+                "fault_plan": fault_plan or None,
+                "jitter_window": jitter_window or None,
+                "metrics": metrics,
+                "int_telemetry": int_telemetry or None,
+            },
+        )
     values = np.asarray(values, dtype=np.int64)
     if max_value is None:
         max_value = int(values.max(initial=0))
@@ -567,6 +624,150 @@ def run_pipeline(
             np.testing.assert_array_equal(
                 row_order, np.argsort(values, kind="stable")
             )
+    return result
+
+
+#: What the wide-key path runs on; every other choice is refused.
+WIDE_KEY_PATH = (
+    'engine="device", merge_backend="arena", range_mode="static", '
+    'pool_backend="numpy", with no metrics, network, recovery, faults, '
+    "jitter or INT telemetry"
+)
+
+
+def _run_pipeline_wide(
+    keys: np.ndarray,
+    *,
+    topology: str,
+    num_flows: int,
+    payload_size: int,
+    num_segments: int,
+    segment_length: int,
+    interleave_mode: str,
+    seed: int,
+    backend: str,
+    k: int,
+    num_servers: int,
+    pool_backend: str,
+    reorder_capacity: int | None,
+    payload: np.ndarray | None,
+    verify: bool,
+    tracer,
+    topo_kw: dict,
+    refused: dict,
+) -> PipelineResult:
+    """:func:`run_pipeline` for ``(n, B)`` uint8 keys (its docstring's
+    "Wide keys").  ``refused`` holds the run's other options, each None
+    where unset; any that is set raises, naming the supported path."""
+    given = sorted(name for name, v in refused.items() if v is not None)
+    if pool_backend != "numpy":
+        given.append("pool_backend")
+    if reorder_capacity is not None and reorder_capacity < 1:
+        given.append("reorder_capacity")
+    if given:
+        raise ValueError(
+            f"keys wider than 64 bits do not support {', '.join(given)}; "
+            f"they run on {WIDE_KEY_PATH}"
+        )
+    keys = np.asarray(keys)
+    n, key_bytes = keys.shape
+    if keys.dtype != np.uint8 or key_bytes not in WIDE_KEY_BYTES:
+        raise ValueError(
+            f"wide keys are (n, 9..16) uint8, got {keys.shape} {keys.dtype}"
+        )
+    if n == 0:
+        raise ValueError("a wide-key job needs at least one key")
+    if payload is not None:
+        payload = np.asarray(payload)
+        if payload.shape[0] != n:
+            raise ValueError(f"payload rows {payload.shape[0]} != {n} keys")
+    # A records job allocates and frees gigabytes of host columns; the
+    # process keeps them for its next job instead of faulting them in anew.
+    keep_freed_memory()
+    tr = tracer or NULL_TRACER
+    with tr.span("pipeline", cat="pipeline", n=n):
+        with tr.span("pipeline:ingress", cat="pipeline", keys=n):
+            words = key_words(keys)
+            # The flows carry row numbers; each key's words follow its row.
+            rows = interleave_batch(
+                split_flows(np.arange(n, dtype=np.int64), num_flows,
+                            payload_size),
+                interleave_mode,
+                seed=seed,
+            )
+            arrivals = WireBatch(
+                np.take(words, rows.values, axis=0), rows.flow_id, rows.seq,
+                rows.segment_id, row_index=rows.values,
+            )
+        ranges = set_ranges_wide(key_bytes, num_segments)
+        topo = make_topology(
+            topology,
+            num_segments=num_segments,
+            segment_length=segment_length,
+            max_value=256**key_bytes - 1,
+            ranges=ranges,
+            backend=backend,
+            engine="device",
+            payload_size=payload_size,
+            **topo_kw,
+        )
+        with tr.span("epoch:0", cat="pipeline", keys=n):
+            delivered, hop_stats = topo.run_batch(arrivals, tracer=tracer)
+        pool = ServerPool(
+            num_segments,
+            num_servers,
+            k=k,
+            reorder_capacity=reorder_capacity,
+            merge_backend="arena",
+            tracer=tracer,
+        )
+        pool.ingest_grouped(
+            delivered.grouped_values, delivered.seg_counts,
+            delivered.run_flags,
+        )
+        out, passes = pool.finish()
+        row_order = out[:, -1].astype(np.int64)
+        output = words_to_keys(out[:, :-1], key_bytes)
+        sorted_payload = None
+        if payload is not None:
+            with tr.span(
+                "pipeline:payload", cat="pipeline", bytes=int(payload.nbytes)
+            ):
+                # ``take`` copies whole rows, several times faster than
+                # fancy indexing for rows of a few dozen bytes.
+                sorted_payload = np.take(payload, row_order, axis=0)
+        with tr.span("pipeline:result", cat="pipeline"):
+            # A segment's keys cross the wire in their emission order, the
+            # order of the delivery's grouped stream: its slices are the
+            # per-segment streams, without a host sort.
+            result = PipelineResult(
+                output=output,
+                passes=passes,
+                hop_stats=hop_stats,
+                segment_multisets=np.split(
+                    delivered.grouped_values[:, :-1],
+                    np.cumsum(delivered.seg_counts)[:-1],
+                ),
+                max_reorder_depth=pool.max_reorder_depth,
+                server_seconds=pool.makespan_seconds,
+                n=n,
+                range_mode="static",
+                ranges_history=[ranges],
+                engine="device",
+                delivered=delivered,
+                num_servers=num_servers,
+                merge_backend="arena",
+                per_server_seconds=list(pool.per_server_seconds),
+                pool_merge_seconds=pool.merge_seconds,
+                server_keys=pool.server_keys,
+                server_imbalance=pool.server_imbalance,
+                sorted_payload=sorted_payload,
+                payload_row_order=row_order,
+            )
+    if verify:
+        want = np.lexsort(keys.T[::-1])
+        np.testing.assert_array_equal(row_order, want)
+        np.testing.assert_array_equal(output, keys[want])
     return result
 
 

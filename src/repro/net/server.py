@@ -325,7 +325,7 @@ class StreamingServer:
         ingesting the same stream packet by packet in order.
         """
         values = np.asarray(values)
-        m = int(values.size)
+        m = len(values)
         if m == 0:
             return
         if sid < 0 or sid >= self.num_segments:
@@ -500,7 +500,7 @@ class StreamingServer:
                 )
         else:
             out = np.concatenate(outs)
-        assert out.size == self._ingested
+        assert len(out) == self._ingested
         return out, passes
 
 

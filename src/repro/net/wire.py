@@ -55,7 +55,7 @@ def ragged_gather(starts: np.ndarray, sizes: np.ndarray) -> np.ndarray:
 class WireBatch:  # __eq__/__hash__ would raise; compare columns explicitly
     """A packet stream as columns; one row per key, wire (arrival) order."""
 
-    values: np.ndarray  # (n,) int64 keys
+    values: np.ndarray  # (n,) int64 keys, or wide keys' (n, W) int32 words
     flow_id: np.ndarray  # (n,) originating storage server / emitting hop
     seq: np.ndarray  # (n,) per-(flow, segment) packet sequence number
     segment_id: np.ndarray  # (n,) the paper's port number (UNTAGGED pre-switch)
@@ -73,11 +73,15 @@ class WireBatch:  # __eq__/__hash__ would raise; compare columns explicitly
     tenant: np.ndarray | None = None  # (n,) int64, opt-in
 
     def __post_init__(self) -> None:
+        # Keys wider than 64 bits ride as a matrix of int32 sort words, one
+        # row per key (:func:`repro.core.partition.key_words`).
+        wide = np.ndim(self.values) == 2
         for name in ("values", "flow_id", "seq", "segment_id"):
+            dtype = np.int32 if wide and name == "values" else np.int64
             object.__setattr__(
-                self, name, np.asarray(getattr(self, name), dtype=np.int64)
+                self, name, np.asarray(getattr(self, name), dtype=dtype)
             )
-        n = self.values.size
+        n = len(self.values)
         for name in ("flow_id", "seq", "segment_id"):
             if getattr(self, name).size != n:
                 raise ValueError(f"column {name} length != values length {n}")
@@ -104,7 +108,7 @@ class WireBatch:  # __eq__/__hash__ would raise; compare columns explicitly
                 )
 
     def __len__(self) -> int:
-        return int(self.values.size)
+        return len(self.values)
 
     # -- packet-boundary view ------------------------------------------
     def packet_starts(self) -> np.ndarray:
@@ -311,7 +315,8 @@ def concat_batches(batches: list[WireBatch]) -> WireBatch:
     if carrying and all(b.tenant is not None for b in carrying):
         tenant = np.concatenate([b.tenant for b in carrying])
     return WireBatch(
-        np.concatenate([b.values for b in batches]),
+        # Empty parts add no keys (and carry no word matrix's width).
+        np.concatenate([b.values for b in carrying or batches]),
         np.concatenate([b.flow_id for b in batches]),
         np.concatenate([b.seq for b in batches]),
         np.concatenate([b.segment_id for b in batches]),

@@ -225,34 +225,46 @@ def test_epoch_program_names_its_stages():
 @pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
 @pytest.mark.parametrize(
     "topo,carry_rows",
-    [("single", False), ("leaf_spine", False), ("single", True)],
-    ids=["keys_single", "keys_leaf_spine", "records_packed"],
+    [("single", False), ("leaf_spine", False), ("single", True),
+     ("single", "wide")],
+    ids=["keys_single", "keys_leaf_spine", "records_packed", "wide_keys"],
 )
 def test_epoch_program_has_no_scatter(topo, carry_rows):
     """Ranks, counts, the block layout and the emission order lower without
     a scatter (the Pallas kernel is off: interpret-mode Pallas is plain HLO
-    with scatters of its own)."""
+    with scatters of its own); wide keys (10-byte keys as three sort words,
+    the rows riding as the fourth) too."""
     import jax
 
-    from repro.core.partition import set_ranges
+    from repro.core.partition import set_ranges, set_ranges_wide
     from repro.kernels.ops import x64_scope
     from repro.net.device_epoch import _epoch_program
     from repro.net.topology import HopGraph, HopNode
 
     max_value = scenario_max_value("drifting")
-    spec = HopSpec(
-        SEGS, LENGTH, max_value, set_ranges(max_value, SEGS), payload_size=32
+    ranges = (
+        set_ranges_wide(10, SEGS) if carry_rows == "wide"
+        else set_ranges(max_value, SEGS)
     )
+    spec = HopSpec(SEGS, LENGTH, max_value, ranges, payload_size=32)
     if topo == "single":
         graph, group_ns = HopGraph((HopNode("switch"),), num_groups=1), (1000,)
     else:
         graph, group_ns = leaf_spine_graph(2), (500, 500)
     fn = _epoch_program(
-        graph, spec, spec.ranges, group_ns, carry_rows, False, None, False
+        graph, spec, spec.ranges, group_ns, bool(carry_rows), False, None,
+        False,
     )
-    args = tuple(jax.ShapeDtypeStruct((g,), np.int64) for g in group_ns)
+    if carry_rows == "wide":
+        args = (
+            tuple(jax.ShapeDtypeStruct((3, g), np.int32) for g in group_ns),
+            tuple(jax.ShapeDtypeStruct((g,), np.int32) for g in group_ns),
+        )
+    else:
+        keys = tuple(jax.ShapeDtypeStruct((g,), np.int64) for g in group_ns)
+        args = (keys, keys if carry_rows else ())
     with x64_scope():
-        text = fn.lower(args, args if carry_rows else ()).as_text()
+        text = fn.lower(*args).as_text()
     assert "stablehlo.sort" in text
     assert "stablehlo.scatter" not in text
 

@@ -4,9 +4,9 @@ Interpret-mode tests (``test_kernels.py``) say nothing about what Mosaic
 accepts.  These compile each kernel the dataplane runs on the chip for a
 *described* v5e (``jax.experimental.topologies``) at the shapes it uses:
 the device epoch's block sort ``sort_rows_padded`` on int32 ``(R, 64)`` and
-``(R, 32)`` — also inside an x64 scope, as the epoch traces it — the
-row merge, and the run-arena tournament at the largest bucket under the
-VMEM cap.  Each asserts that the kernel (``tpu_custom_call``) is in the
+``(R, 32)`` — also inside an x64 scope, as the epoch traces it — and on
+stacked sort words, the row merge, and the run-arena tournament at the
+largest bucket under the VMEM cap.  Each asserts that the kernel (``tpu_custom_call``) is in the
 compiled program.  ``chip_smoke.py`` runs them on the chip.
 """
 
@@ -94,5 +94,31 @@ def test_int64_keys_compile_without_a_kernel(one_chip):
         text = _compiled_text(
             lambda x: ops.merge_tournament(x, interpret=False),
             one_chip, ((16, 64), jnp.int64),
+        )
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64_scope"])
+@pytest.mark.parametrize("words", [4, 5])
+def test_multi_word_block_sort_compiles(one_chip, words, x64):
+    """The wide-key block sort: 10- and 16-byte keys as 3 and 4 sort words
+    plus the row, stacked (K, R, 64) int32."""
+    with ops.x64_scope() if x64 else jax.enable_x64(False):
+        text = _compiled_text(
+            lambda x: ops.sort_rows_padded(x, interpret=False),
+            one_chip, ((words, 60, 64), jnp.int32),
+        )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("words,B", [(4, 64), (5, 128)])
+def test_multi_word_tournament_compiles_without_a_kernel(one_chip, words, B):
+    """The wide records' arena tournament lowers through XLA, at the
+    largest bucket the arena gives one call."""
+    cap = 1 << ((bitonic.TOURNAMENT_MAX_ELEMS // words).bit_length() - 1)
+    with ops.x64_scope():
+        text = _compiled_text(
+            lambda x: ops.merge_tournament(x, interpret=False),
+            one_chip, ((words, cap // B, B), jnp.int32),
         )
     assert "tpu_custom_call" not in text
